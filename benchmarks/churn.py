@@ -23,7 +23,8 @@ Acceptance (claim checks):
   * end-state recall@10 within 0.01 of a from-scratch ``ivf.build`` on
     the live rows (and exactly matching a same-quantizer repack).
 
-``--devices N`` appends a sharded cell (forced host devices, subprocess):
+``--devices N`` appends a sharded cell (the first N chips in this process
+on a TPU; forced host devices in a subprocess on the CPU):
 the same controller loop over ``ivf_sharded``, with deletes concentrated
 on the lowest id ranks so shard 0 drains and the controller's imbalance
 trigger fires a ``shard_rebalance`` — recall must survive the migration.
@@ -324,8 +325,13 @@ def churn_sharded_cell(n: int, dim: int, queries: int, lists: int,
 
 
 def _run_sharded_cell(devices: int, **kw) -> dict:
-    """Spawn ``churn_sharded_cell`` under a forced host-device count (the
-    XLA flag must be set before jax initializes, hence the subprocess)."""
+    """Run ``churn_sharded_cell`` over ``devices`` devices. On a TPU it runs
+    in this process on the first ``devices`` chips (this process holds them;
+    a child could not reach them). Elsewhere it spawns a child under a
+    forced host-device count (the XLA flag must be set before jax
+    initializes, hence the subprocess)."""
+    if jax.default_backend() == "tpu":
+        return churn_sharded_cell(devices=devices, **kw)
     code = (
         "import os, json\n"
         "os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '') + "
@@ -358,8 +364,9 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="small corpus / few steps (CI churn-smoke scale)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="append the sharded churn cell on N forced host "
-                         "devices (subprocess)")
+                    help="append the sharded churn cell on N devices (the "
+                         "first N chips on a TPU; forced host devices in a "
+                         "subprocess on the CPU)")
     ap.add_argument("--out", default=None,
                     help="BENCH_churn.json destination dir (default "
                          "$REPRO_BENCH_DIR; unset → print only)")

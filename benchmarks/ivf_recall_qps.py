@@ -9,8 +9,9 @@ queries, and rotation through each registered searcher:
                 the IVF build, so "recall vs flat" isolates probing loss)
   * ivf       — ``nprobe`` sweep: scan work vs recall, the serving knob
   * *_sharded — the row-sharded twins, attached to the same artifacts
-                (parity rows in-process; the ``--devices N`` sweep runs a
-                forced-host-device subprocess and measures per-device scan
+                (parity rows in-process; the ``--devices N`` sweep runs on
+                the first N chips in-process on a TPU, or in a forced-host-
+                device subprocess on the CPU, and measures per-device scan
                 work vs the replicated backend)
 
 Metrics per row:
@@ -125,8 +126,13 @@ def sharded_cell(n: int, dim: int, queries: int, lists: int, subspaces: int,
 
 
 def _run_sharded_cell(devices: int, **kw) -> dict:
-    """Spawn ``sharded_cell`` under ``--xla_force_host_platform_device_count``
-    (the flag must be set before jax initializes, hence the subprocess)."""
+    """Run ``sharded_cell`` over ``devices`` devices. On a TPU it runs in
+    this process on the first ``devices`` chips (this process holds them; a
+    child could not reach them). Elsewhere it spawns a child under
+    ``--xla_force_host_platform_device_count`` (the flag must be set before
+    jax initializes, hence the subprocess)."""
+    if jax.default_backend() == "tpu":
+        return sharded_cell(devices=devices, **kw)
     code = (
         "import os, json\n"
         # append rather than overwrite: inherited platform/memory flags must
@@ -152,10 +158,12 @@ def _run_sharded_cell(devices: int, **kw) -> dict:
 
 def run(n: int = 100_000, dim: int = 64, queries: int = 256, lists: int = 256,
         subspaces: int = 16, codewords: int = 256, depths=(1, 2),
-        use_kernel: bool = False, verbose: bool = True, devices: int = 1):
+        use_kernel: bool | None = None, verbose: bool = True,
+        devices: int = 1):
     """Sweep the searcher registry × residual depths; returns
     (results dict, claim-check dict). ``devices > 1`` appends the sharded
-    scan-work sweep on a forced-host-device subprocess."""
+    scan-work sweep (``_run_sharded_cell``). ``use_kernel=None`` scans with
+    the Pallas kernels on a TPU and the jnp references elsewhere."""
     out = print if verbose else (lambda *a, **k: None)
     key = jax.random.PRNGKey(0)
     X = synthetic.sift_like(key, n, dim)
@@ -434,11 +442,13 @@ def main() -> None:
     ap.add_argument("--codewords", type=int, default=256)
     ap.add_argument("--depths", default="1,2",
                     help="comma list of residual depths (1=PQ, 2=RQ-2, ...)")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="Pallas path (TPU; interpret mode is too slow here)")
+    ap.add_argument("--use-kernel", action="store_const", const=True,
+                    help="force the Pallas kernels (default: on a TPU "
+                         "only; interpret mode is too slow on the CPU)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="run the sharded sweep on N forced host devices "
-                         "(subprocess)")
+                    help="run the sharded sweep on N devices (the first N "
+                         "chips on a TPU; forced host devices in a "
+                         "subprocess on the CPU)")
     ap.add_argument("--out", default=None,
                     help="BENCH_ivf_recall_qps.json destination dir "
                          "(default $REPRO_BENCH_DIR; unset → print only)")

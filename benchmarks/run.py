@@ -42,13 +42,16 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--devices", type=int, default=1,
                     help="ivf/churn sections: run the sharded cells on N "
-                         "forced host devices (subprocess)")
+                         "devices (the first N chips on a TPU; forced host "
+                         "devices in a subprocess on the CPU)")
     ap.add_argument("--out", default=None,
                     help="BENCH_*.json destination dir (default "
                          "$REPRO_BENCH_DIR; --fast falls back to the "
                          "tracked benchmarks/ trajectory)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     def want(name: str) -> bool:
         return only is None or name in only

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro.index import search as index_search
 from repro.kernels import ops as kops
+from repro.kernels.common import use_kernels
 
 
 @jax.tree_util.register_dataclass
@@ -66,7 +67,8 @@ def empty(capacity: int, code_width: int, code_dtype, *,
 
 
 def staged_topk(buf: StagingBuffer, QR: jax.Array, lut, centroids, k: int, *,
-                use_kernel: bool = False) -> tuple[jax.Array, jax.Array]:
+                use_kernel: bool | None = None
+                ) -> tuple[jax.Array, jax.Array]:
     """The flat-ADC side pass: score every staged row under the SAME LUT
     pack the main scan streams (staged rows are encoded against the same
     frozen quantizers, so one LUT build serves both lanes) and return a
@@ -74,7 +76,7 @@ def staged_topk(buf: StagingBuffer, QR: jax.Array, lut, centroids, k: int, *,
     the ids operand — the buffer scans at fixed shape whatever its fill."""
     lut, scales = index_search.split_lut_pack(lut)
     res = kops.adc_lookup(lut, buf.codes, scales, buf.ids,
-                          use_kernel=use_kernel)          # (b, cap_b)
+                          use_kernel=use_kernels(use_kernel))  # (b, cap_b)
     coarse = QR @ centroids.T                             # (b, L)
     scores = res + jnp.take(coarse, buf.lists, axis=1)
     return index_search.topk_padded(scores, buf.ids, k)
@@ -82,7 +84,8 @@ def staged_topk(buf: StagingBuffer, QR: jax.Array, lut, centroids, k: int, *,
 
 def merge_staged(res: index_search.SearchResult, buf: StagingBuffer,
                  QR: jax.Array, lut, centroids, k: int, *,
-                 use_kernel: bool = False) -> index_search.SearchResult:
+                 use_kernel: bool | None = None
+                 ) -> index_search.SearchResult:
     """Fold the staging side pass into a main-scan result: concatenate the
     two padded top-k runs and re-top-k (``kernels.ops.topk_merge`` — the
     one merge the sharded searchers already use). ``scanned`` grows by the

@@ -1,93 +1,47 @@
-"""Version-compat shims over JAX APIs that moved between release lines.
+"""The ambient-mesh probe.
 
-The repo targets both the installed 0.4.x line and current JAX:
-
-  * ``shard_map``: ``jax.experimental.shard_map.shard_map(check_rep=...)``
-    on 0.4.x became top-level ``jax.shard_map(check_vma=...)``.
-  * mesh construction with ``axis_types`` lives in
-    ``launch.mesh.make_mesh_compat`` (kept there because the launch layer
-    owns mesh policy; it is the same guard pattern as here).
-
-  * ``current_mesh``: probing the ambient mesh context was only ever
-    possible through the private ``jax._src.mesh.thread_resources``; newer
-    JAX exposes ``jax.sharding.get_abstract_mesh``. The helper tries the
-    public API first.
-
-Every call site goes through these wrappers instead of feature-testing
-inline.
+``current_mesh`` reports the mesh of the innermost active mesh context. On
+the installed JAX (0.9) the two context managers are visible through two
+different probes: ``jax.set_mesh`` through the public
+``jax.sharding.get_abstract_mesh``, and the ``with mesh:`` form only through
+the private ``jax._src.mesh.thread_resources`` (``get_abstract_mesh`` stays
+empty inside it). Both are in use — the tests and the launch layer enter
+``with mesh:`` — so the helper asks both.
 """
 from __future__ import annotations
 
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across JAX generations.
-
-    ``check_vma`` follows the new-API name; it is translated to the old
-    ``check_rep`` kwarg on 0.4.x. ``None`` leaves the library default.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
 def _has_manual_axes(mesh) -> bool:
     """True when any mesh axis is Manual — i.e. we are inside a shard_map
-    body on new JAX, where sharding constraints over those axes are invalid
-    (legacy JAX had no Manual axis type: always False there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return False
-    try:
-        types = getattr(mesh, "axis_types", ())
-        values = types.values() if hasattr(types, "values") else types
-        return any(t == axis_type.Manual for t in values)
-    except Exception:  # pragma: no cover
-        return False
+    body, where sharding constraints over those axes are invalid."""
+    types = getattr(mesh, "axis_types", ())
+    values = types.values() if hasattr(types, "values") else types
+    return any(t == jax.sharding.AxisType.Manual for t in values)
 
 
 def current_mesh():
     """The mesh of the innermost active mesh context, or ``None``.
 
-    Tries the public ``jax.sharding.get_abstract_mesh`` (new JAX: the
-    ``use_mesh`` context) first and falls back to the legacy private
-    ``thread_resources`` probe (0.4.x: the ``with mesh:`` context). Both
-    probes returning nothing — i.e. no mesh context is active — yields
-    ``None``, which ``sharding.rules.constrain`` treats as "do not
-    constrain" (see the no-op unit test in tests/test_distributed.py).
+    Tries the public ``jax.sharding.get_abstract_mesh`` (the ``set_mesh``
+    context) first and then ``thread_resources`` (the ``with mesh:``
+    context — see the module docstring). No active context yields ``None``,
+    which ``sharding.rules.constrain`` treats as "do not constrain" (see the
+    no-op unit test in tests/test_distributed.py).
 
-    Inside a shard_map body on new JAX the context mesh carries Manual
-    axes; that is reported as ``None`` too — constraining over manual axes
-    is an error, and on legacy JAX shard_map bodies likewise saw no mesh
-    (thread_resources is only set by ``with mesh:``). Callers that need
-    concrete devices (e.g. ``search.sharded.resolve_mesh`` placing index
-    shards) must additionally check for a non-abstract mesh — new JAX's
-    ``use_mesh`` context yields an AbstractMesh with no device list.
+    Inside a shard_map body the context mesh carries Manual axes; that is
+    reported as ``None`` too — constraining over manual axes is an error.
+    Callers that need concrete devices (e.g. ``search.sharded.resolve_mesh``
+    placing index shards) must additionally check for a non-abstract mesh —
+    the ``set_mesh`` context yields an AbstractMesh with no device list.
     """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            mesh = get_abstract()
-            if mesh is not None and not mesh.empty \
-                    and not _has_manual_axes(mesh):
-                return mesh
-        except Exception:  # pragma: no cover — fall through to the legacy probe
-            pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty or _has_manual_axes(mesh):
-            return None
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is not None and not mesh.empty and not _has_manual_axes(mesh):
         return mesh
-    except Exception:  # pragma: no cover
+    from jax._src.mesh import thread_resources
+
+    mesh = thread_resources.env.physical_mesh
+    if mesh.empty or _has_manual_axes(mesh):
         return None
+    return mesh

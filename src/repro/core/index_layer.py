@@ -113,4 +113,4 @@ def adc_scores(params: IndexLayerParams, queries: jax.Array,
     through the shared ADC kernel family (jnp oracle path off-TPU).
     """
     tables = quantizer(params).adc_tables(queries @ params.R)
-    return quant.adc_score_tables(tables, codes, use_kernel=False)
+    return quant.adc_score_tables(tables, codes)

@@ -27,15 +27,7 @@ import numpy as np
 
 from repro import quant
 from repro.kernels import ops as kops
-from repro.kernels.common import INTERPRET
-
-
-def _default_use_kernel(use_kernel: bool | None) -> bool:
-    """Kernel dispatch default for the decode hot path: the Pallas member of
-    the ADC family on real TPUs, its jnp oracle elsewhere — interpret mode
-    loops the grid in Python and would cripple non-TPU decode. Pass an
-    explicit bool to override (the parity tests force both paths)."""
-    return (not INTERPRET) if use_kernel is None else use_kernel
+from repro.kernels.common import use_kernels
 
 
 class KVQuantConfig(NamedTuple):
@@ -129,7 +121,7 @@ def adc_scores_grouped(params: KVQuantParams, q: jax.Array, k_codes: jax.Array,
     lut = params.quant_k.adc_tables((q @ params.rot_k).reshape(g * r, hd))
     lut = lut.reshape(g, r, *lut.shape[1:])  # (g, r, D, K)
     return kops.adc_batch(lut, k_codes,
-                          use_kernel=_default_use_kernel(use_kernel))
+                          use_kernel=use_kernels(use_kernel))
 
 
 def adc_scores(params: KVQuantParams, q: jax.Array, k_codes: jax.Array,

@@ -201,19 +201,36 @@ def build(key: jax.Array, X: jax.Array, R: jax.Array, cfg: IVFPQConfig, *,
     build is offline; serving (search/maintain) is the jit'd hot path.
     """
     kc, kp = jax.random.split(key)
-    XR = X @ R
-    XT = XR if train_size is None else XR[:train_size]
+    XT = (X if train_size is None else X[:train_size]) @ R
     coarse = quant.VQ.fit(kc, XT, cfg.num_lists, iters=coarse_iters)
     train_lists = coarse.assign(XT)
     quantizer, _ = quant.fit_quantizer(
         kp, XT - coarse.centroids[train_lists], cfg.pq,
         depth=cfg.depth, iters=pq_iters,
     )
-    list_ids, codes = encode(XR, coarse, quantizer)
+    del XT, train_lists
+    # encode in row chunks: the (rows, L) coarse and (rows, D, K) residual
+    # assignment scores of a whole corpus would not fit on one device
+    parts = [_encode_rows(X[i:i + ENCODE_ROWS], R, coarse, quantizer)
+             for i in range(0, X.shape[0], ENCODE_ROWS)]
+    list_ids = np.concatenate([np.asarray(p[0]) for p in parts])
+    codes = np.concatenate([np.asarray(p[1]) for p in parts])
     if ids is None:
         ids = jnp.arange(X.shape[0], dtype=jnp.int32)
     return pack(R, coarse, quantizer, codes, list_ids, ids,
                 block_size=cfg.block_size)
+
+
+#: corpus rows ``build`` rotates, assigns and encodes per device step.
+ENCODE_ROWS = 16384
+
+
+@jax.jit
+def _encode_rows(X: jax.Array, R: jax.Array, coarse: quant.VQ,
+                 quantizer: quant.Quantizer) -> tuple[jax.Array, jax.Array]:
+    """``encode`` of one chunk of raw rows, codes in their storage dtype."""
+    list_ids, codes = encode(X @ R, coarse, quantizer)
+    return list_ids, codes.astype(quantizer.code_dtype)
 
 
 # ---------------------------------------------------------------------------

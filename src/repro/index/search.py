@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from repro.index.ivf import IVFPQIndex
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels.common import use_kernels
 from repro.sharding import rules as sh
 
 NEG_INF = -jnp.inf
@@ -126,9 +127,11 @@ def candidate_blocks(index: IVFPQIndex, lists: jax.Array,
 
 def _search_core(index: IVFPQIndex, QR: jax.Array, lut, *,
                  nprobe: int, k: int, max_blocks: int,
-                 use_kernel: bool) -> SearchResult:
+                 use_kernel: bool | None) -> SearchResult:
     """Probe + scan + top-k over already-rotated queries and built LUTs.
-    ``lut`` is a LUT pack (plain f32 array or (qlut, scales))."""
+    ``lut`` is a LUT pack (plain f32 array or (qlut, scales)).
+    ``use_kernel=None`` scans with the Pallas kernel on a TPU backend and
+    the jnp reference elsewhere (``kernels.common.use_kernels``)."""
     b = QR.shape[0]
     bs = index.block_size
     QR = sh.constrain(QR, ("act_batch", None), sh.IVF_RULES)
@@ -147,7 +150,7 @@ def _search_core(index: IVFPQIndex, QR: jax.Array, lut, *,
     # adding the finite coarse term afterwards cannot resurrect them
     res_scores = kops.ivf_adc(
         lut, index.codes, block_idx, block_query, scales, index.ids,
-        block_size=bs, use_kernel=use_kernel,
+        block_size=bs, use_kernel=use_kernels(use_kernel),
     ).reshape(b, nprobe, max_blocks, bs)
     scores = res_scores + cscores[:, :, None, None]            # + coarse term
 
@@ -169,7 +172,7 @@ def _search_core(index: IVFPQIndex, QR: jax.Array, lut, *,
     static_argnames=("nprobe", "k", "max_blocks", "use_kernel", "lut_dtype"),
 )
 def search_fixed(index: IVFPQIndex, Q: jax.Array, *, nprobe: int, k: int = 10,
-                 max_blocks: int, use_kernel: bool = True,
+                 max_blocks: int, use_kernel: bool | None = None,
                  lut_dtype: str = "float32") -> SearchResult:
     """Jit-friendly core: ``max_blocks`` (the per-list probe window in tiles,
     ≥ index.max_list_blocks() for exactness) is passed statically."""
@@ -186,7 +189,7 @@ def search_fixed(index: IVFPQIndex, Q: jax.Array, *, nprobe: int, k: int = 10,
 )
 def search_prepared(index: IVFPQIndex, QR: jax.Array, lut, *,
                     nprobe: int, k: int = 10, max_blocks: int,
-                    use_kernel: bool = True) -> SearchResult:
+                    use_kernel: bool | None = None) -> SearchResult:
     """``search_fixed`` with the rotate + LUT-build steps hoisted out: the
     caller supplies ``QR = Q·R`` and a LUT pack (``build_luts`` output).
     The ``search.Engine`` uses this to reuse cached per-query LUTs."""
@@ -195,7 +198,8 @@ def search_prepared(index: IVFPQIndex, QR: jax.Array, lut, *,
 
 
 def search(index: IVFPQIndex, Q: jax.Array, *, nprobe: int, k: int = 10,
-           use_kernel: bool = True, lut_dtype: str = "float32") -> SearchResult:
+           use_kernel: bool | None = None,
+           lut_dtype: str = "float32") -> SearchResult:
     """Batched ANN search: (b, n) queries -> top-k (scores, ids, scanned).
 
     Convenience wrapper that reads the probe-window size off the concrete
@@ -210,7 +214,7 @@ def search(index: IVFPQIndex, Q: jax.Array, *, nprobe: int, k: int = 10,
 
 
 def flat_adc_scores(index: IVFPQIndex, Q: jax.Array, *,
-                    use_kernel: bool = False,
+                    use_kernel: bool | None = None,
                     lut_dtype: str = "float32") -> tuple[jax.Array, jax.Array]:
     """Flat baseline over the same quantized representation: score every CSR
     row (coarse term + residual ADC). Returns ((b, cap) scores with holes at
@@ -222,14 +226,15 @@ def flat_adc_scores(index: IVFPQIndex, Q: jax.Array, *,
 
 
 def flat_adc_prepared(index: IVFPQIndex, QR: jax.Array, lut, *,
-                      use_kernel: bool = False) -> tuple[jax.Array, jax.Array]:
+                      use_kernel: bool | None = None
+                      ) -> tuple[jax.Array, jax.Array]:
     """``flat_adc_scores`` with rotate + LUT-build hoisted out (Engine LUT
     cache entry point, mirroring ``search_prepared``). ``lut`` is a LUT
     pack."""
     lut, scales = split_lut_pack(lut)
     # holes/tombstones (id < 0) are masked to −inf inside the tile body
     res = kops.adc_lookup(lut, index.codes, scales, index.ids,
-                          use_kernel=use_kernel)  # (b, cap)
+                          use_kernel=use_kernels(use_kernel))  # (b, cap)
     # coarse term per row: row r belongs to list l iff offsets[l] ≤ r < offsets[l+1]
     row_list = jnp.searchsorted(
         index.list_offsets, jnp.arange(index.capacity), side="right"

@@ -8,7 +8,8 @@ LUT block at the same group, sharing the one-hot-MXU tile body
 (adc_common.adc_tile_scores).
 
 Grid (g, S/bn): step (gi, i) scores tile i of group gi's codes against that
-group's r LUTs. Residual depth rides in the Dp column dimension.
+group's r LUTs. Residual depth rides in the Dp column dimension; ``bn``
+comes from the one-hot VMEM budget (``adc_common.scan_block_rows``).
 """
 from __future__ import annotations
 
@@ -18,20 +19,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.adc_common import adc_tile_scores
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.adc_common import adc_tile_scores, scan_block_rows
+from repro.kernels.common import cdiv, interpret_mode
 
 
 def _kernel(codes_ref, lut_ref, out_ref):
-    scores = adc_tile_scores(codes_ref[0], lut_ref[0])  # (bn, r)
-    out_ref[...] = scores.T[None].astype(out_ref.dtype)
+    scores = adc_tile_scores(codes_ref[0], lut_ref[0])  # (r, bn)
+    out_ref[...] = scores[None].astype(out_ref.dtype)
 
 
 def _kernel_q(codes_ref, lut_ref, scales_ref, out_ref):
     # quantized path: the group's r LUTs ride in int8/uint8 + (r, Dp, 2)
     # scales; dequant happens in VMEM
     scores = adc_tile_scores(codes_ref[0], lut_ref[0], scales_ref[0])
-    out_ref[...] = scores.T[None].astype(out_ref.dtype)
+    out_ref[...] = scores[None].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -41,7 +42,7 @@ def adc_batch(
     scales: jax.Array | None = None,
     *,
     block_s: int = 1024,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """lut (g, r, Dp, K) float, codes (g, S, Dp) integer
     ->  scores (g, r, S) float32.
@@ -50,7 +51,7 @@ def adc_batch(
     pack — the per-step LUT DMA moves 4× fewer bytes."""
     g, r, Dp, K = lut.shape
     S = codes.shape[1]
-    bs = min(block_s, S)
+    bs = scan_block_rows(S, Dp, K, block_s)
     grid = (g, cdiv(S, bs))
     in_specs = [
         pl.BlockSpec((1, bs, Dp), lambda gi, i: (gi, i, 0)),
@@ -71,5 +72,5 @@ def adc_batch(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, r, bs), lambda gi, i: (gi, 0, i)),
         out_shape=jax.ShapeDtypeStruct((g, r, S), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)

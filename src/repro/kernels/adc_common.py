@@ -6,7 +6,8 @@ IVF selected-block scan (ivf_adc.py), and the grouped KV-cache scorer
 tables with the same **one-hot matmul trick** (DESIGN.md §2): gathers are
 lane-hostile on TPU, so the (bn, Dp·K) one-hot expansion of the code tile is
 contracted against the reshaped LUT on the MXU. The one-hot tile lives only
-in VMEM and is rebuilt per grid step.
+in VMEM and is rebuilt per grid step, so its size (``scan_block_rows``)
+sets how many code rows a step can take.
 
 The family is parameterized by residual depth purely through the column
 dimension: a depth-M residual quantizer presents ``Dp = M·D`` code columns
@@ -71,16 +72,34 @@ def dequantize_luts(qlut: jax.Array, scales: jax.Array) -> jax.Array:
             + scales[..., 1][..., None])
 
 
+#: VMEM bytes the f32 (rows, Dp, K) one-hot tile of one scan step may take.
+#: The TPU compiler scopes a kernel's intermediates to 16 MiB by default.
+ONEHOT_VMEM_BYTES = 8 << 20
+
+
+def scan_block_rows(rows: int, Dp: int, K: int, preferred: int = 1024) -> int:
+    """Code rows per scan step: the most that keep the one-hot tile within
+    ``ONEHOT_VMEM_BYTES`` (so the block shrinks as RQ depth grows Dp), a
+    multiple of 128 because the rows land on the lane axis of the scores,
+    and all of them when there are fewer."""
+    fit = max(128, (ONEHOT_VMEM_BYTES // (Dp * K * 4)) // 128 * 128)
+    bn = min(preferred, fit)
+    return rows if rows <= bn else bn
+
+
 def adc_tile_scores(codes: jax.Array, lut: jax.Array,
                     scales: jax.Array | None = None) -> jax.Array:
     """Score one code tile against a LUT batch inside a kernel body.
 
-    codes (bn, Dp) integer, lut (b, Dp, K) float -> (bn, b) float32 with
-    out[n, q] = Σ_d lut[q, d, codes[n, d]].
+    codes (bn, Dp) integer, lut (b, Dp, K) float -> (b, bn) float32 with
+    out[q, n] = Σ_d lut[q, d, codes[n, d]] — rows on the lane axis, so every
+    caller stores a lane-dense tile.
 
     With ``scales`` (b, Dp, 2) the lut is an integer table from
     quantize_luts and is dequantized here, in VMEM, after the cheap int
-    load — the whole point: only the int8 bytes cross HBM.
+    load — the whole point: only the int8 bytes cross HBM. The contraction
+    runs at full f32 precision: the one-hot side is exact, and the sums must
+    match the gather reference, not a bf16 pass of it.
     """
     codes = codes.astype(jnp.int32)
     if scales is not None:
@@ -91,8 +110,9 @@ def adc_tile_scores(codes: jax.Array, lut: jax.Array,
     iota = jax.lax.broadcasted_iota(jnp.int32, (bn, Dp, K), 2)
     onehot = (iota == codes[:, :, None]).astype(jnp.float32)
     return jax.lax.dot_general(
-        onehot.reshape(bn, Dp * K),
         lut.reshape(b, Dp * K),
+        onehot.reshape(bn, Dp * K),
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )  # (bn, b)
+    )  # (b, bn)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, interpret_mode
 
 
 def _kernel(gi_ref, gj_ref, ri_ref, rj_ref, out_ref, acc_ref, accT_ref, *, nk):
@@ -54,7 +54,7 @@ def gcd_score(
     *,
     block: int = 256,
     block_k: int = 512,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ):
     """A = GᵀR − RᵀG for G, R (n, n). Returns float32 (n, n) antisymmetric."""
     n = G.shape[0]
@@ -77,5 +77,5 @@ def gcd_score(
             pltpu.VMEM((b, b), jnp.float32),  # M tile accumulator
             pltpu.VMEM((b, b), jnp.float32),  # Mᵀ tile accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(G, G, R, R)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, interpret_mode
 
 
 def _kernel(c_ref, s_ref, xe_ref, xo_ref, ye_ref, yo_ref):
@@ -41,7 +41,7 @@ def givens_rotate(
     *,
     block_m: int = 256,
     block_p: int = 256,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ):
     """xe/xo: (m, p) paired column planes; c/s: (p,) cos/sin. -> (ye, yo)."""
     m, p = xe.shape
@@ -67,5 +67,5 @@ def givens_rotate(
             pl.BlockSpec((bm, bp), lambda i, j: (i, j)),
         ),
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(c2, s2, xe, xo)

@@ -16,7 +16,9 @@ one-hot expanded over K and contracted against the query's (D·K) LUT row on
 the MXU, exactly like the flat kernel.
 
 Grid: one step per selected (query, block) pair; out[s] = scores of the
-``block_size`` items of that tile. With an ``ids`` operand the tile's id row
+``block_size`` items of that tile, stored as a lane-dense (1, 1, bs) block
+of an (S, 1, bs) array (a (1, bs) block of (S, bs) would break the TPU's
+(8, 128) tiling rule). With an ``ids`` operand the tile's id row
 is DMA'd alongside its codes (steered by the same ``block_idx`` index_map)
 and rows with id < 0 — CSR padding holes and tombstoned deletes — score
 −inf inside the tile body, so a delete is one id write and masked rows can
@@ -35,31 +37,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.adc_common import adc_tile_scores
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import interpret_mode
 
 
 def _kernel(bi_ref, bq_ref, codes_ref, lut_ref, out_ref):
     del bi_ref, bq_ref  # consumed by the index_maps
-    bn = codes_ref.shape[0]
-    # shared family body with b = 1 (this step's query LUT): (bn, 1)
+    # shared family body with b = 1 (this step's query LUT): (1, bs)
     scores = adc_tile_scores(codes_ref[...], lut_ref[...])
-    out_ref[...] = scores.reshape(1, bn).astype(out_ref.dtype)
+    out_ref[...] = scores[None].astype(out_ref.dtype)
 
 
 def _kernel_q(bi_ref, bq_ref, codes_ref, lut_ref, scales_ref, out_ref):
     del bi_ref, bq_ref  # consumed by the index_maps
-    bn = codes_ref.shape[0]
     # quantized path: this step's LUT row rides in as int8/uint8 + its
     # (1, Dp, 2) scale row; dequant happens in VMEM
     scores = adc_tile_scores(codes_ref[...], lut_ref[...], scales_ref[...])
-    out_ref[...] = scores.reshape(1, bn).astype(out_ref.dtype)
+    out_ref[...] = scores[None].astype(out_ref.dtype)
 
 
 def _kernel_m(bi_ref, bq_ref, codes_ref, lut_ref, ids_ref, out_ref):
     del bi_ref, bq_ref  # consumed by the index_maps
-    bn = codes_ref.shape[0]
-    scores = adc_tile_scores(codes_ref[...], lut_ref[...]).reshape(1, bn)
-    # (1, bn) id tile of this codes block: holes/tombstones → −inf
+    scores = adc_tile_scores(codes_ref[...], lut_ref[...])[None]
+    # (1, 1, bs) id tile of this codes block: holes/tombstones → −inf
     scores = jnp.where(ids_ref[...] >= 0, scores, -jnp.inf)
     out_ref[...] = scores.astype(out_ref.dtype)
 
@@ -67,9 +66,8 @@ def _kernel_m(bi_ref, bq_ref, codes_ref, lut_ref, ids_ref, out_ref):
 def _kernel_qm(bi_ref, bq_ref, codes_ref, lut_ref, scales_ref, ids_ref,
                out_ref):
     del bi_ref, bq_ref  # consumed by the index_maps
-    bn = codes_ref.shape[0]
     scores = adc_tile_scores(
-        codes_ref[...], lut_ref[...], scales_ref[...]).reshape(1, bn)
+        codes_ref[...], lut_ref[...], scales_ref[...])[None]
     scores = jnp.where(ids_ref[...] >= 0, scores, -jnp.inf)
     out_ref[...] = scores.astype(out_ref.dtype)
 
@@ -84,7 +82,7 @@ def ivf_adc(
     ids: jax.Array | None = None,
     *,
     block_size: int = 128,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """lut (b, Dp, K) float, codes (cap, Dp) int (cap % block_size == 0),
     block_idx / block_query (S,) int32  ->  scores (S, block_size) float32.
@@ -107,25 +105,28 @@ def ivf_adc(
         in_specs.append(pl.BlockSpec((1, Dp, 2), lambda i, bi, bq: (bq[i], 0, 0)))
         operands.append(scales)
     if ids is not None:
-        # the id column folded to (cap/bs, bs) tiles so the SAME block_idx
-        # prefetch steers its DMA as steers the codes tile
-        in_specs.append(pl.BlockSpec((1, block_size),
-                                     lambda i, bi, bq: (bi[i], 0)))
+        # the id column folded to (cap/bs, 1, bs) tiles so the SAME
+        # block_idx prefetch steers its DMA as steers the codes tile; the
+        # unit middle axis keeps each block's last two dims TPU-tileable
+        in_specs.append(pl.BlockSpec((1, 1, block_size),
+                                     lambda i, bi, bq: (bi[i], 0, 0)))
         operands.append(
-            ids.reshape(codes.shape[0] // block_size, block_size)
+            ids.reshape(codes.shape[0] // block_size, 1, block_size)
             .astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_size), lambda i, bi, bq: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_size),
+                               lambda i, bi, bq: (i, 0, 0)),
     )
     # codes stay in their storage dtype (uint8 for K ≤ 256) all the way to
     # VMEM — the kernel widens per tile; widening here would materialize a
     # 4× int32 copy of the whole corpus per call.
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, block_size), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((S, 1, block_size), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(block_idx.astype(jnp.int32), block_query.astype(jnp.int32), *operands)
+    return out.reshape(S, block_size)

@@ -13,12 +13,17 @@ whenever the delta is purely within-subspace.
 
 ``colmap`` (Dp, D) is a one-hot column map from code column → query
 subspace: identity for PQ, and for a depth-M level-major RQ the column
-l·D + d maps to subspace d. Keeping it an explicit operand lets one kernel
+l·D + d maps to subspace d. Keeping it an explicit argument lets one kernel
 serve every quantizer layout — the Dp axis of the codebooks is the true
 code-column axis, so per-column int8 scale groups stay correct for RQ.
 
-Grid (b/bb,): each step rotates one query block on the MXU and contracts it
-against the whole (Dp, K, sub) codebook block resident in VMEM.
+Grid (b/bb,): each step holds one query block, and a loop over the code
+columns p rotates it by column p's slice of the query transform and
+contracts the result against column p's codebook on the MXU. The wrapper
+lays the operands out so the kernel needs no reshape: the transform
+as (Dp, sub, n) per-column row slices (the colmap gather happens there, on
+an (n, n) matrix, never on corpus state), the codebooks as (Dp, sub, K),
+and the output as (Dp, b, K), transposed back to (b, Dp, K) outside.
 """
 from __future__ import annotations
 
@@ -28,27 +33,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, interpret_mode
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(q_ref, qd_ref, cb_ref, cm_ref, out_ref):
-    # rotate the query block in VMEM: (bb, n) @ (n, n)
-    QL = jnp.dot(q_ref[...].astype(jnp.float32),
-                 qd_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    bb = QL.shape[0]
-    Dp, K, sub = cb_ref.shape
-    D = cm_ref.shape[1]
-    QLs = QL.reshape(bb, D, sub)
-    # expand query subspaces to code columns via the one-hot map: (Dp, bb, sub)
-    Qexp = jax.lax.dot_general(
-        cm_ref[...].astype(jnp.float32), QLs,
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    # batched contraction over sub against the codebooks: (Dp, bb, K)
-    lut = jax.lax.dot_general(
-        Qexp, cb_ref[...].astype(jnp.float32),
-        (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32)
-    out_ref[...] = jnp.transpose(lut, (1, 0, 2)).astype(out_ref.dtype)
+def _kernel(q_ref, qcol_ref, cbt_ref, out_ref):
+    q = q_ref[...].astype(jnp.float32)                      # (bb, n)
+
+    def column(p, carry):
+        # rotate the query block into column p's subspace: (bb, sub)
+        ql = jax.lax.dot_general(
+            q, qcol_ref[p].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+        # contract against column p's codewords: (bb, K)
+        out_ref[p] = jnp.dot(
+            ql, cbt_ref[p].astype(jnp.float32), precision=_HIGHEST,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, qcol_ref.shape[0], column, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -59,7 +63,7 @@ def fused_lut(
     colmap: jax.Array,
     *,
     block_b: int = 8,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Q (b, n) raw queries, qdelta (n, n), cb_flat (Dp, K, sub) frozen
     flattened codebooks, colmap (Dp, D) one-hot column map
@@ -72,17 +76,20 @@ def fused_lut(
     bpad = cdiv(b, bb) * bb
     if bpad != b:
         Q = jnp.pad(Q, ((0, bpad - b), (0, 0)))
+    # qcol[p, s, i] = qdelta[i, d(p)·sub + s] with d(p) column p's subspace
+    qdt = qdelta.astype(jnp.float32).T.reshape(D, sub, n)
+    qcol = qdt[jnp.argmax(colmap, axis=1)]                  # (Dp, sub, n)
+    cbt = jnp.swapaxes(cb_flat.astype(jnp.float32), 1, 2)   # (Dp, sub, K)
     out = pl.pallas_call(
         _kernel,
         grid=(bpad // bb,),
         in_specs=[
             pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-            pl.BlockSpec((Dp, K, sub), lambda i: (0, 0, 0)),
-            pl.BlockSpec((Dp, D), lambda i: (0, 0)),
+            pl.BlockSpec((Dp, sub, n), lambda i: (0, 0, 0)),
+            pl.BlockSpec((Dp, sub, K), lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bb, Dp, K), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bpad, Dp, K), jnp.float32),
-        interpret=interpret,
-    )(Q, qdelta, cb_flat, colmap.astype(jnp.float32))
-    return out[:b]
+        out_specs=pl.BlockSpec((Dp, bb, K), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Dp, bpad, K), jnp.float32),
+        interpret=interpret_mode(interpret),
+    )(Q, qcol, cbt)
+    return jnp.swapaxes(out, 0, 1)[:b]

@@ -8,7 +8,9 @@ in HBM.
 
 Grid (D, m/bm): one subspace × one row tile per step; the full (K, sub)
 codebook slice for that subspace rides along in VMEM (K ≤ 256, sub ≤ 128 →
-≤128 KiB).
+≤128 KiB). The codes leave as a lane-dense (1, 1, bm) block of a (D, 1, m)
+array — a (bm, 1) column block would break the TPU's (8, 128) tiling rule —
+and are transposed to (m, D) outside.
 """
 from __future__ import annotations
 
@@ -18,17 +20,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, interpret_mode
 
 
 def _kernel(x_ref, cb_ref, out_ref):
     x = x_ref[0].astype(jnp.float32)          # (bm, sub)
     cb = cb_ref[0].astype(jnp.float32)        # (K, sub)
     dots = jax.lax.dot_general(
-        x, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (bm, K)
-    cn = jnp.sum(jnp.square(cb), axis=-1)[None, :]  # (1, K)
-    out_ref[...] = jnp.argmin(cn - 2.0 * dots, axis=-1).astype(jnp.int32)[:, None]
+        cb, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (K, bm): rows on lanes
+    cn = jnp.sum(jnp.square(cb), axis=-1, keepdims=True)  # (K, 1)
+    d2 = cn - 2.0 * dots
+    # argmin over K (first index of the minimum, as jnp.argmin) from two
+    # min-reductions, so the codes come out lane-major: (1, bm)
+    best = jnp.min(d2, axis=0, keepdims=True)
+    k = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    codes = jnp.min(jnp.where(d2 == best, k, d2.shape[0]), axis=0,
+                    keepdims=True)
+    out_ref[...] = codes[None]
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -37,7 +46,7 @@ def pq_assign(
     codebooks: jax.Array,
     *,
     block_m: int = 512,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """X (m, n), codebooks (D, K, sub) with n = D·sub  ->  codes (m, D) int32."""
     m, n = X.shape
@@ -53,8 +62,8 @@ def pq_assign(
             pl.BlockSpec((1, bm, sub), lambda d, i: (d, i, 0)),
             pl.BlockSpec((1, K, sub), lambda d, i: (d, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, 1), lambda d, i: (i, d)),
-        out_shape=jax.ShapeDtypeStruct((m, D), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, 1, bm), lambda d, i: (d, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((D, 1, m), jnp.int32),
+        interpret=interpret_mode(interpret),
     )(Xs, codebooks)
-    return out
+    return out[:, 0, :].T

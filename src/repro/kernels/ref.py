@@ -71,14 +71,19 @@ def fused_lut_ref(Q: jax.Array, qdelta: jax.Array, cb_flat: jax.Array,
 
     This is the oracle for kernels/lut_build.py: the delta is applied to the
     query block inside the tile body, so refresh never rebuilds corpus-side
-    state."""
-    QL = Q.astype(jnp.float32) @ qdelta.astype(jnp.float32)        # (b, n)
+    state. Full f32 precision, as the kernel: on a TPU the default matmul
+    precision would round the operands to bf16."""
+    hi = jax.lax.Precision.HIGHEST
+    QL = jnp.dot(Q.astype(jnp.float32), qdelta.astype(jnp.float32),
+                 precision=hi)                                      # (b, n)
     b, n = QL.shape
     Dp, K, sub = cb_flat.shape
     D = colmap.shape[1]
     QLs = QL.reshape(b, D, sub)
-    Qexp = jnp.einsum("pd,bds->bps", colmap.astype(jnp.float32), QLs)
-    return jnp.einsum("bps,pks->bpk", Qexp, cb_flat.astype(jnp.float32))
+    Qexp = jnp.einsum("pd,bds->bps", colmap.astype(jnp.float32), QLs,
+                      precision=hi)
+    return jnp.einsum("bps,pks->bpk", Qexp, cb_flat.astype(jnp.float32),
+                      precision=hi)
 
 
 def adc_batch_ref(lut: jax.Array, codes: jax.Array,
@@ -110,6 +115,10 @@ def adc_batch_ref(lut: jax.Array, codes: jax.Array,
     return out
 
 
+#: selected blocks ``ivf_adc_ref`` scores per step
+_REF_STEPS = 1024
+
+
 def ivf_adc_ref(lut: jax.Array, codes: jax.Array, block_idx: jax.Array,
                 block_query: jax.Array, *, block_size: int = 128,
                 scales: jax.Array | None = None,
@@ -125,20 +134,29 @@ def ivf_adc_ref(lut: jax.Array, codes: jax.Array, block_idx: jax.Array,
     processes (the added coarse term is finite and cannot resurrect them)."""
     if scales is not None:
         lut = dequantize_luts(lut, scales)
-    D = lut.shape[1]
-    rows = block_idx[:, None] * block_size + jnp.arange(block_size)  # (S, bn)
-    c = codes[rows].astype(jnp.int32)  # gather in storage dtype, widen after
-    # (S, D, K) LUT replication below is notation, not allocation: XLA fuses
-    # the gather chain into the reduction (benchmark runs 100k × nprobe=64
-    # through this path without a materialized l_sel).
-    l_sel = lut[block_query.astype(jnp.int32)]                       # (S, D, K)
-    g = jnp.take_along_axis(
-        l_sel[:, None, :, :], c[..., None], axis=-1
-    )[..., 0]                                                        # (S, bn, D)
-    out = jnp.sum(g, axis=-1).astype(jnp.float32)
-    if ids is not None:
-        out = jnp.where(ids[rows] >= 0, out, -jnp.inf)
-    return out
+
+    def scan(sched):
+        bi, bq = sched
+        rows = bi[:, None] * block_size + jnp.arange(block_size)     # (s, bn)
+        c = codes[rows].astype(jnp.int32)  # gather in storage dtype, widen
+        l_sel = lut[bq.astype(jnp.int32)]                            # (s, D, K)
+        g = jnp.take_along_axis(
+            l_sel[:, None, :, :], c[..., None], axis=-1
+        )[..., 0]                                                    # (s, bn, D)
+        out = jnp.sum(g, axis=-1).astype(jnp.float32)
+        if ids is not None:
+            out = jnp.where(ids[rows] >= 0, out, -jnp.inf)
+        return out
+
+    # the schedule is scanned in steps of _REF_STEPS: the TPU lowers the
+    # gather with an index array of (steps, bn, D, 3) int32 — 9.4 GB for
+    # one 64-query batch at nprobe 32 over a 1.2M-row index if taken at once
+    S = block_idx.shape[0]
+    step = max(1, min(S, _REF_STEPS))
+    pad = (-S) % step
+    sched = tuple(jnp.pad(a.astype(jnp.int32), (0, pad)).reshape(-1, step)
+                  for a in (block_idx, block_query))
+    return jax.lax.map(scan, sched).reshape(-1, block_size)[:S]
 
 
 def embedding_bag_ref(table: jax.Array, indices: jax.Array, bag_ids: jax.Array,

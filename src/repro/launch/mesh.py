@@ -25,16 +25,11 @@ from repro.roofline.analysis import (  # noqa: F401  (compat re-exports)
 
 
 def make_mesh_compat(shape, axes, **kwargs):
-    """``jax.make_mesh`` across JAX API generations.
-
-    Newer JAX requires explicit ``axis_types`` (``jax.sharding.AxisType``)
-    for Auto axes; older releases (≤0.4.x) have neither the kwarg nor the
-    enum. All mesh construction in this repo funnels through here so both
-    generations work unmodified.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs.setdefault("axis_types", (axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with Auto axes (the sharding rules annotate with
+    ``with_sharding_constraint``, which needs Auto, and ``jax.make_mesh``
+    does not default to it). All mesh construction in this repo funnels
+    through here."""
+    kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
     return jax.make_mesh(shape, axes, **kwargs)
 
 

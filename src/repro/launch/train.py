@@ -34,6 +34,7 @@ import numpy as np
 from repro import configs, obs, rotations
 from repro.data import pipeline as pipe_lib
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import gnn, recsys
 from repro.models import transformer as tfm
@@ -263,6 +264,7 @@ def main():
                     help="double-buffer host batch synthesis + device_put "
                          "on a worker thread (bit-identical stream)")
     args = ap.parse_args()
+    compile_cache.enable()
     _, hist = train(args.arch, args.steps, args.batch, args.ckpt_dir,
                     resume=not args.no_resume, full=args.full,
                     rotation=args.rotation, obs_log=args.obs_log,

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops as kops
 
 
@@ -83,7 +82,7 @@ def sharded_lookup(table: jax.Array, ids: jax.Array, mesh, axis: str = "model",
         out = jnp.where(ok[..., None], out, 0.0)
         return jax.lax.psum(out, axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(table_spec, ids_spec), out_specs=P(),
         check_vma=False,
     )(table, ids)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.kernels.common import use_kernels
 
 
 def split(X: jax.Array, D: int) -> jax.Array:
@@ -101,15 +102,16 @@ def adc_score(lut: jax.Array, codes: jax.Array) -> jax.Array:
 
 
 def adc_score_tables(tables: jax.Array, codes: jax.Array, *,
-                     use_kernel: bool = True) -> jax.Array:
+                     use_kernel: bool | None = None) -> jax.Array:
     """Score PQ/RQ codes against protocol-shaped ADC tables.
 
     ``tables (b, code_width, K)`` (any Quantizer.adc_tables output — residual
     depth is already flattened into ``code_width``) × ``codes
     (N, code_width)`` -> (b, N). Dispatches to the fused Pallas flat-scan
-    kernel (kernels/adc_lookup.py) or its jnp oracle.
+    kernel (kernels/adc_lookup.py) or its jnp oracle; ``use_kernel=None``
+    picks by platform (``kernels.common.use_kernels``).
     """
-    return kops.adc_lookup(tables, codes, use_kernel=use_kernel)
+    return kops.adc_lookup(tables, codes, use_kernel=use_kernels(use_kernel))
 
 
 def rotate_codebooks(codebooks: jax.Array, pi: jax.Array, pj: jax.Array,

@@ -146,8 +146,6 @@ def kmeans_sharded(key: jax.Array, X: jax.Array, cfg: PQConfig, *, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     S = mesh.shape[axis]
     m = X.shape[0]
     pad = (-m) % S
@@ -156,7 +154,7 @@ def kmeans_sharded(key: jax.Array, X: jax.Array, cfg: PQConfig, *, mesh,
     w = jnp.concatenate([jnp.ones((m,), jnp.float32),
                          jnp.zeros((pad,), jnp.float32)])
 
-    step = compat.shard_map(
+    step = jax.shard_map(
         functools.partial(_sharded_lloyd_step, axis=axis),
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),

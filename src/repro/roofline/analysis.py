@@ -121,9 +121,7 @@ def analyze(compiled, lowered=None, model_flops_total: float | None = None,
     scan-dominated cells, so scaling the totals is a ≲10% approximation —
     recorded here rather than hidden.
     """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # 0.4.x returns [dict], newer a dict
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0)) * loop_trips
     byts = float(cost.get("bytes accessed", 0.0)) * loop_trips
     hlo = compiled.as_text()

@@ -64,8 +64,10 @@ class SearchConfig(NamedTuple):
     ``block_size`` the CSR/Pallas tile, ``train_size`` caps the k-means
     sample. ``nprobe`` is the ``ivf`` backend's default probe width (a
     per-call override exists). ``exact`` only reads ``tile_rows`` — the
-    corpus tile of its streaming brute-force scan. ``use_kernel`` toggles
-    the Pallas kernels (False = jnp reference path, the CPU/CI default).
+    corpus tile of its streaming brute-force scan. ``use_kernel`` picks the
+    Pallas kernels (True) or their jnp references (False); None — the
+    default — resolves at build time to the kernels on a TPU backend and the
+    references elsewhere (``kernels.common.use_kernels``).
 
     ``lut_dtype`` quantizes the ADC lookup tables the scan kernels stream
     ("float32" | "int8" | "uint8" — integer dtypes store per-subspace
@@ -84,7 +86,7 @@ class SearchConfig(NamedTuple):
     block_size: int = 128
     tile_rows: int = 4096
     train_size: int | None = None
-    use_kernel: bool = False
+    use_kernel: bool | None = None
     lut_dtype: str = "float32"
     fused_refresh: bool = False
 

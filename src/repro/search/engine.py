@@ -76,10 +76,12 @@ from repro.search.base import SearchResult, Searcher
 
 def _lut_to_host(lut):
     """Host copy of a LUT pack (plain (b, Dp, K) array or (qlut, scales)
-    tuple — see index/search.py ``split_lut_pack``)."""
+    tuple — see index/search.py ``split_lut_pack``). Always a copy: the
+    device pack is donated to the scan right after, and a cached row must
+    never alias a donated buffer (``np.asarray`` may return a view of it)."""
     if isinstance(lut, tuple):
-        return tuple(np.asarray(p) for p in lut)
-    return np.asarray(lut)
+        return tuple(np.array(p) for p in lut)
+    return np.array(lut)
 
 
 def _lut_row(lut_host, i: int):

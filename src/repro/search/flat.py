@@ -46,6 +46,7 @@ from repro.index import ivf as index_ivf
 from repro.index import search as index_search
 from repro.index.ivf import IVFPQIndex
 from repro.kernels import ops as kops
+from repro.kernels.common import use_kernels
 from repro.search.base import SearchConfig, SearchResult, topk_padded
 
 
@@ -71,8 +72,8 @@ class ADCState:
     index: IVFPQIndex
     nprobe: int = dataclasses.field(default=8, metadata={"static": True})
     max_blocks: int = dataclasses.field(default=-1, metadata={"static": True})
-    use_kernel: bool = dataclasses.field(
-        default=False, metadata={"static": True})
+    use_kernel: bool | None = dataclasses.field(
+        default=None, metadata={"static": True})
     lut_dtype: str = dataclasses.field(
         default="float32", metadata={"static": True})
     rot: jax.Array | None = None     # (n, n) live rotation R₀·Δ (fused)
@@ -107,7 +108,7 @@ def _adc_stats(name: str, state: ADCState) -> dict:
         code_bytes_per_row=code_bytes,
         compression=float(index.dim * 4 / code_bytes),
         memory_bytes=int(index.codes.size * index.codes.dtype.itemsize),
-        use_kernel=state.use_kernel,
+        use_kernel=use_kernels(state.use_kernel),
         lut_dtype=state.lut_dtype,
         fused_refresh=state.rot is not None,
     )
@@ -157,7 +158,7 @@ def _luts(state: ADCState, QR: jax.Array):
     if state.qdelta is not None:
         cb_flat, colmap = state.index.quantizer.lut_operands()
         lut = kops.fused_lut(QR, state.qdelta, cb_flat, colmap,
-                             use_kernel=state.use_kernel)
+                             use_kernel=use_kernels(state.use_kernel))
     else:
         lut = state.index.quantizer.adc_tables(QR)
     if state.lut_dtype != "float32":
@@ -223,12 +224,12 @@ class FlatADC:
                            fused_refresh=cfg.fused_refresh)
 
     @staticmethod
-    def attach(index: IVFPQIndex, *, use_kernel: bool = False,
+    def attach(index: IVFPQIndex, *, use_kernel: bool | None = None,
                lut_dtype: str = "float32",
                fused_refresh: bool = False) -> ADCState:
         """State over an existing index — flat-scan the very codes another
         backend probes (the parity-test and benchmark-sharing entry)."""
-        state = ADCState(index=index, use_kernel=use_kernel,
+        state = ADCState(index=index, use_kernel=use_kernels(use_kernel),
                          max_blocks=index.max_list_blocks(),
                          lut_dtype=lut_dtype)
         return _fused_state(state) if fused_refresh else state
@@ -236,7 +237,7 @@ class FlatADC:
     @staticmethod
     def from_quantizer(R: jax.Array, quantizer, corpus: jax.Array, *,
                        block_size: int = 128,
-                       use_kernel: bool = False) -> ADCState:
+                       use_kernel: bool | None = None) -> ADCState:
         """Serve a *pre-fit* quantizer (e.g. the PQ that OPQ's alternating
         minimization learned jointly with R) without refitting: the corpus
         is encoded as ``quantizer.encode(corpus @ R)`` under a single

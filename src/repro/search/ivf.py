@@ -25,6 +25,7 @@ from repro.churn import buffer as churn_buffer
 from repro.index import ivf as index_ivf
 from repro.index import search as index_search
 from repro.index.ivf import IVFPQIndex
+from repro.kernels.common import use_kernels
 from repro.search import flat
 from repro.search.base import SearchConfig, SearchResult
 from repro.search.flat import ADCState, _adc_stats, _refresh
@@ -47,13 +48,14 @@ class IVF:
 
     @staticmethod
     def attach(index: IVFPQIndex, *, nprobe: int = 8,
-               use_kernel: bool = False, lut_dtype: str = "float32",
+               use_kernel: bool | None = None, lut_dtype: str = "float32",
                fused_refresh: bool = False) -> ADCState:
         """State over an existing index (captures the static probe window)."""
         state = ADCState(index=index,
                          nprobe=min(nprobe, index.num_lists),
                          max_blocks=index.max_list_blocks(),
-                         use_kernel=use_kernel, lut_dtype=lut_dtype)
+                         use_kernel=use_kernels(use_kernel),
+                         lut_dtype=lut_dtype)
         return flat._fused_state(state) if fused_refresh else state
 
     def effective_nprobe(self, state: ADCState, nprobe: int | None) -> int:

@@ -15,7 +15,7 @@ parallel, merge per-partition top-k):
                    SHARED coarse quantizer but scans only its local lists
 
 All three run the existing single-device scan as the shard-local body of a
-``compat.shard_map``: per-shard arrays (rotated corpus / CSR codes, ids,
+``jax.shard_map``: per-shard arrays (rotated corpus / CSR codes, ids,
 list offsets) are stacked on a leading shard axis and partitioned with the
 ``ivf_sharded`` rule table (sharding/rules.py — corpus rows over
 ("pod", "data")), while R, the coarse centroids, and the residual
@@ -64,6 +64,7 @@ from repro.index import maintain
 from repro.index import search as index_search
 from repro.index.ivf import IVFPQIndex
 from repro.kernels import ops as kops
+from repro.kernels.common import use_kernels
 from repro.search import exact as exact_mod
 from repro.search import flat as flat_mod
 from repro.search.base import SearchConfig, SearchResult, topk_padded
@@ -79,7 +80,7 @@ def resolve_mesh(mesh: Mesh | None = None,
     it has a shard axis), else a fresh 1-axis mesh over every device.
 
     The ambient mesh must be a concrete ``Mesh`` — shard placement needs
-    real devices, and new JAX's ``use_mesh`` context reports an
+    real devices, and the ``jax.set_mesh`` context reports an
     AbstractMesh (no device list), which cannot place index shards.
     """
     if mesh is not None:
@@ -228,7 +229,7 @@ def _exact_sharded_search(state: ShardedExactState, Q: jax.Array,
         return SearchResult(scores=scores, ids=ids,
                             scanned=jax.lax.psum(res.scanned, axes))
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         local, mesh=state.mesh,
         in_specs=(P(), _shard_spec(axes), _shard_spec(axes), P()),
         out_specs=SearchResult(scores=P(), ids=P(), scanned=P()),
@@ -339,8 +340,8 @@ class ShardedADCState:
     nprobe: int = dataclasses.field(default=8, metadata={"static": True})
     max_blocks: int = dataclasses.field(default=-1,
                                         metadata={"static": True})
-    use_kernel: bool = dataclasses.field(default=False,
-                                         metadata={"static": True})
+    use_kernel: bool | None = dataclasses.field(default=None,
+                                                metadata={"static": True})
     axes: tuple[str, ...] = dataclasses.field(default=("data",),
                                               metadata={"static": True})
     lut_dtype: str = dataclasses.field(default="float32",
@@ -372,7 +373,7 @@ def _fused_sharded_state(state: ShardedADCState) -> ShardedADCState:
 
 def attach_shards(parts: list[IVFPQIndex], *, mesh: Mesh | None = None,
                   axis: AxisSpec = "auto", nprobe: int = 8,
-                  use_kernel: bool = False, lut_dtype: str = "float32",
+                  use_kernel: bool | None = None, lut_dtype: str = "float32",
                   fused_refresh: bool = False) -> ShardedADCState:
     """Stack per-shard indexes (``ivf.shard_split`` or ``ivf.build_sharded``
     output) into one servable sharded state.
@@ -436,7 +437,7 @@ def attach_shards(parts: list[IVFPQIndex], *, mesh: Mesh | None = None,
         mesh=mesh, block_size=head.block_size,
         nprobe=min(nprobe, head.num_lists),
         max_blocks=max(max(p.max_list_blocks() for p in parts), 1),
-        use_kernel=use_kernel, axes=axes, lut_dtype=lut_dtype,
+        use_kernel=use_kernels(use_kernel), axes=axes, lut_dtype=lut_dtype,
     )
     return _fused_sharded_state(state) if fused_refresh else state
 
@@ -479,7 +480,7 @@ def _sharded_scan(state: ShardedADCState, QR: jax.Array, lut,
         return SearchResult(scores=scores, ids=out_ids,
                             scanned=jax.lax.psum(res.scanned, axes))
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         local, mesh=state.mesh,
         in_specs=(P(), _replicated_specs(state.coarse),
                   _replicated_specs(state.quantizer),
@@ -583,7 +584,7 @@ def _sharded_adc_stats(name: str, state: ShardedADCState) -> dict:
         compression=float(state.coarse.dim * 4 / code_bytes),
         memory_bytes=mem,
         memory_bytes_per_device=mem // S,
-        use_kernel=state.use_kernel,
+        use_kernel=use_kernels(state.use_kernel),
         lut_dtype=state.lut_dtype,
         fused_refresh=state.rot is not None,
         **_shard_rows_stats(ids),
@@ -591,7 +592,7 @@ def _sharded_adc_stats(name: str, state: ShardedADCState) -> dict:
 
 
 def _shard_existing(index: IVFPQIndex, mesh: Mesh | None, axis: AxisSpec, *,
-                    nprobe: int, use_kernel: bool,
+                    nprobe: int, use_kernel: bool | None,
                     lut_dtype: str = "float32",
                     fused_refresh: bool = False) -> ShardedADCState:
     mesh = resolve_mesh(mesh, axis)
@@ -617,7 +618,7 @@ def _luts(state: ShardedADCState, QR: jax.Array):
     if state.qdelta is not None:
         cb_flat, colmap = state.quantizer.lut_operands()
         lut = kops.fused_lut(QR, state.qdelta, cb_flat, colmap,
-                             use_kernel=state.use_kernel)
+                             use_kernel=use_kernels(state.use_kernel))
     else:
         lut = state.quantizer.adc_tables(QR)
     if state.lut_dtype != "float32":
@@ -645,7 +646,7 @@ class FlatSharded:
     @staticmethod
     def attach(index: IVFPQIndex, *, mesh: Mesh | None = None,
                axis: AxisSpec = "auto", nprobe: int = 8,
-               use_kernel: bool = False, lut_dtype: str = "float32",
+               use_kernel: bool | None = None, lut_dtype: str = "float32",
                fused_refresh: bool = False) -> ShardedADCState:
         """Shard an existing replicated index across the mesh — the very
         codes the single-device backends serve, redistributed (the parity
@@ -707,7 +708,7 @@ class IVFSharded:
     @staticmethod
     def attach(index: IVFPQIndex, *, mesh: Mesh | None = None,
                axis: AxisSpec = "auto", nprobe: int = 8,
-               use_kernel: bool = False, lut_dtype: str = "float32",
+               use_kernel: bool | None = None, lut_dtype: str = "float32",
                fused_refresh: bool = False) -> ShardedADCState:
         """Shard an existing replicated index across the mesh (see
         ``FlatSharded.attach`` — one state serves both sharded ADC

@@ -302,7 +302,7 @@ def constrain(x, logical_axes, rules, mesh=None):
 
 
 def _current_mesh():
-    """Ambient mesh context via the version-guarded ``compat`` probe."""
+    """Ambient mesh context via the ``compat.current_mesh`` probe."""
     from repro import compat
 
     return compat.current_mesh()

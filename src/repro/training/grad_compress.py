@@ -63,15 +63,13 @@ def make_compressed_crosspod_psum(mesh, axis: str = "pod"):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     n = mesh.shape[axis]
 
     def f(g, err):
         g_sum, err_new = ef_psum_int8(g[0], err[0], axis, n)
         return g_sum, err_new[None]
 
-    return compat.shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(axis), P(axis)), out_specs=(P(), P(axis)),
         check_vma=False,

@@ -315,17 +315,21 @@ def test_constrain_is_noop_outside_mesh_context():
 
 
 def test_current_mesh_probe_sees_context():
-    """compat.current_mesh resolves the ambient mesh on this JAX version
-    (public get_abstract_mesh first, legacy thread_resources fallback)."""
+    """compat.current_mesh resolves the ambient mesh under both context
+    managers: ``jax.set_mesh`` (seen by the public
+    get_abstract_mesh) and ``with mesh:`` (seen only by thread_resources)."""
+    import jax
+
     from repro import compat
     from repro.launch.mesh import make_host_mesh
 
     mesh = make_host_mesh()
-    with mesh:
-        seen = compat.current_mesh()
-        assert seen is not None and not seen.empty
-        assert set(dict(seen.shape)) == {"data", "model"}
-    assert compat.current_mesh() is None
+    for ctx in (jax.set_mesh(mesh), mesh):
+        with ctx:
+            seen = compat.current_mesh()
+            assert seen is not None and not seen.empty
+            assert set(dict(seen.shape)) == {"data", "model"}
+        assert compat.current_mesh() is None
 
 
 def test_ivf_sharded_rule_table_row_shards():
