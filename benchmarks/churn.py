@@ -48,6 +48,7 @@ import numpy as np
 from repro import churn, rotations, search
 from repro.data import synthetic
 from repro.index import ivf as index_ivf
+from repro.kernels.common import on_tpu
 from repro.metrics import recall_at_k
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -330,7 +331,7 @@ def _run_sharded_cell(devices: int, **kw) -> dict:
     a child could not reach them). Elsewhere it spawns a child under a
     forced host-device count (the XLA flag must be set before jax
     initializes, hence the subprocess)."""
-    if jax.default_backend() == "tpu":
+    if on_tpu():
         return churn_sharded_cell(devices=devices, **kw)
     code = (
         "import os, json\n"

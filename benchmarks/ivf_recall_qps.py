@@ -52,6 +52,7 @@ import numpy as np
 from repro import rotations, search
 from repro.data import synthetic
 from repro.index import maintain
+from repro.kernels.common import on_tpu
 from repro.metrics import recall_at_k
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -131,7 +132,7 @@ def _run_sharded_cell(devices: int, **kw) -> dict:
     child could not reach them). Elsewhere it spawns a child under
     ``--xla_force_host_platform_device_count`` (the flag must be set before
     jax initializes, hence the subprocess)."""
-    if jax.default_backend() == "tpu":
+    if on_tpu():
         return sharded_cell(devices=devices, **kw)
     code = (
         "import os, json\n"

@@ -72,6 +72,11 @@ def _kernel_qm(bi_ref, bq_ref, codes_ref, lut_ref, scales_ref, ids_ref,
     out_ref[...] = scores.astype(out_ref.dtype)
 
 
+#: schedule steps one pallas_call takes: its two int32 schedule arrays then
+#: use 256 KiB of SMEM
+SCHEDULE_STEPS = 32768
+
+
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
 def ivf_adc(
     lut: jax.Array,
@@ -113,20 +118,27 @@ def ivf_adc(
         operands.append(
             ids.reshape(codes.shape[0] // block_size, 1, block_size)
             .astype(jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_size),
-                               lambda i, bi, bq: (i, 0, 0)),
-    )
-    # codes stay in their storage dtype (uint8 for K ≤ 256) all the way to
-    # VMEM — the kernel widens per tile; widening here would materialize a
-    # 4× int32 copy of the whole corpus per call.
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, block_size), jnp.float32),
-        interpret=interpret_mode(interpret),
-    )(block_idx.astype(jnp.int32), block_query.astype(jnp.int32), *operands)
+    out_spec = pl.BlockSpec((1, 1, block_size), lambda i, bi, bq: (i, 0, 0))
+
+    def scan(bi: jax.Array, bq: jax.Array) -> jax.Array:
+        steps = bi.shape[0]
+        # codes stay in their storage dtype (uint8 for K ≤ 256) all the way
+        # to VMEM — the kernel widens per tile; widening here would
+        # materialize a 4× int32 copy of the whole corpus per call.
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(steps,), in_specs=in_specs,
+                out_specs=out_spec),
+            out_shape=jax.ShapeDtypeStruct((steps, 1, block_size),
+                                           jnp.float32),
+            interpret=interpret_mode(interpret),
+        )(bi, bq, *operands)
+
+    # the two prefetched schedule arrays live in SMEM (1 MiB on a v5e): a
+    # longer schedule is scanned in pieces of SCHEDULE_STEPS
+    bi, bq = block_idx.astype(jnp.int32), block_query.astype(jnp.int32)
+    out = jnp.concatenate([
+        scan(bi[s:s + SCHEDULE_STEPS], bq[s:s + SCHEDULE_STEPS])
+        for s in range(0, S, SCHEDULE_STEPS)])
     return out.reshape(S, block_size)

@@ -26,7 +26,9 @@ N_DIM = 512            # embedding width
 DP, K = 64, 256        # code columns × codewords
 ROWS = 1 << 20         # corpus rows
 B = 64                 # query batch
-STEPS = 16384          # selected (query, block) pairs of one IVF scan
+# selected (query, block) pairs of one IVF scan: 256 queries × nprobe 32 ×
+# 16 blocks per list, a schedule longer than SMEM holds in one call
+STEPS = 131072
 F32, U8, I8, I32 = jnp.float32, jnp.uint8, jnp.int8, jnp.int32
 
 

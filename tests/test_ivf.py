@@ -97,6 +97,23 @@ def test_ivf_adc_kernel_matches_ref():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_ivf_adc_long_schedule_in_pieces(monkeypatch):
+    """A schedule longer than one call's SMEM share is scanned in pieces
+    (the last one short) and gives the same scores as in one piece."""
+    from repro.kernels import ivf_adc as ivf_adc_mod
+
+    monkeypatch.setattr(ivf_adc_mod, "SCHEDULE_STEPS", 8)
+    b, cap, bs, S = 3, 24 * 8, 8, 29          # pieces of 8, 8, 8, 5
+    lut = jax.random.normal(jax.random.PRNGKey(11), (b, D, K))
+    codes = jax.random.randint(jax.random.PRNGKey(12), (cap, D), 0, K)
+    bi = jax.random.randint(jax.random.PRNGKey(13), (S,), 0, cap // bs)
+    bq = jax.random.randint(jax.random.PRNGKey(14), (S,), 0, b)
+    got = ops.ivf_adc(lut, codes, bi, bq, block_size=bs, use_kernel=True)
+    want = ref.ivf_adc_ref(lut, codes, bi, bq, block_size=bs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_refresh_subspace_step_is_exact(index_and_data):
     index, X, _ = index_and_data
     G = jax.random.normal(jax.random.PRNGKey(11), (DIM, DIM))
