@@ -22,6 +22,8 @@ from typing import Any, Callable
 
 import jax
 
+from repro import obs
+
 
 class Pipeline:
     """Wraps ``make_batch(key) -> pytree`` into a stateful, resumable iterator.
@@ -70,11 +72,12 @@ class Pipeline:
         return jax.random.fold_in(jax.random.PRNGKey(self._seed), self._step)
 
     def _produce(self, step: int):
-        key = jax.random.fold_in(jax.random.PRNGKey(self._seed), step)
-        batch = self._make(key)
-        if self._shardings is not None:
-            batch = jax.device_put(batch, self._shardings)
-        return batch
+        with obs.annotate("pipeline.produce", step=step):
+            key = jax.random.fold_in(jax.random.PRNGKey(self._seed), step)
+            batch = self._make(key)
+            if self._shardings is not None:
+                batch = jax.device_put(batch, self._shardings)
+            return batch
 
     def _count(self, name: str) -> None:
         if self._registry is not None:

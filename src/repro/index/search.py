@@ -154,15 +154,19 @@ def _search_core(index: IVFPQIndex, QR: jax.Array, lut, *,
     ).reshape(b, nprobe, max_blocks, bs)
     scores = res_scores + cscores[:, :, None, None]            # + coarse term
 
-    rows = blk[..., None] * bs + jnp.arange(bs)                # (b, p, B, bs)
-    cand_ids = index.ids[rows]
-    scores = sh.constrain(
-        scores.reshape(b, -1), ("act_batch", "ivf_cand"), sh.IVF_RULES
-    )
+    # the candidate-id gather and the top-k over every scheduled row, named
+    # in the compiled program's metadata so a profile can read the stage
+    with jax.named_scope("ivf.select"):
+        rows = blk[..., None] * bs + jnp.arange(bs)            # (b, p, B, bs)
+        cand_ids = index.ids[rows]
+        scores = sh.constrain(
+            scores.reshape(b, -1), ("act_batch", "ivf_cand"), sh.IVF_RULES
+        )
 
-    # k can exceed the candidate pool (small nprobe, large k): the shared
-    # contract clamps the top_k and pads back out to (b, k) with (−inf, −1)
-    top_scores, top_ids = topk_padded(scores, cand_ids.reshape(b, -1), k)
+        # k can exceed the candidate pool (small nprobe, large k): the
+        # shared contract clamps the top_k and pads back out to (b, k) with
+        # (−inf, −1)
+        top_scores, top_ids = topk_padded(scores, cand_ids.reshape(b, -1), k)
     scanned = jnp.sum(valid.reshape(b, -1), axis=1) * bs
     return SearchResult(scores=top_scores, ids=top_ids, scanned=scanned)
 

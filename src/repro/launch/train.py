@@ -43,6 +43,13 @@ from repro.training import optimizer as opt_lib
 from repro.training import train_state as ts
 
 
+def _host_seed(key: jax.Array) -> int:
+    """The batch's host RNG seed, drawn from its key: one device read, which
+    waits for whatever the device is running."""
+    with obs.annotate("pipeline.seed"):
+        return int(jax.random.randint(key, (), 0, 1 << 30))
+
+
 def make_batch_fn(cfg, family: str, batch: int):
     """Family-specific synthetic batch maker: key -> tuple of arrays."""
     if family == "lm":
@@ -55,7 +62,7 @@ def make_batch_fn(cfg, family: str, batch: int):
                                       num_classes=cfg.num_classes)
 
         def f(key):
-            seed = int(jax.random.randint(key, (), 0, 1 << 30))
+            seed = _host_seed(key)
             rng = np.random.RandomState(seed)
             seeds = rng.randint(0, g.num_nodes, size=batch)
             feats, labels = graph_lib.sample_blocks(
@@ -72,8 +79,7 @@ def make_batch_fn(cfg, family: str, batch: int):
         log = synthetic.ClickLog(0, cfg.item_vocab, dim=32)
 
         def f(key):
-            seed = int(jax.random.randint(key, (), 0, 1 << 30))
-            return log.batch(seed, batch, cfg.hist_len)
+            return log.batch(_host_seed(key), batch, cfg.hist_len)
         return f
     if isinstance(cfg, recsys.DINConfig):
         def f(key):
@@ -114,6 +120,21 @@ def init_model(key, cfg, family):
     if isinstance(cfg, recsys.DINConfig):
         return recsys.din_init(key, cfg)
     raise TypeError(type(cfg))
+
+
+def build_step(cfg, family: str, steps: int, rotation: str, *,
+               emit_deltas: bool):
+    """The trainer's jitted step (state donated) and its optimizer config,
+    for a schedule of ``steps`` steps with the ``rotation`` learner."""
+    ocfg = opt_lib.OptimizerConfig(
+        lr=1e-3, total_steps=steps, warmup_steps=min(50, steps // 10 + 1),
+        rotation=rotations.RotationConfig.from_spec(rotation),
+    )
+    step_fn = jax.jit(
+        ts.make_train_step(make_loss_fn(cfg, family), ocfg,
+                           emit_deltas=emit_deltas),
+        donate_argnums=(0,))
+    return step_fn, ocfg
 
 
 def _rotation_health(params) -> float | None:
@@ -159,13 +180,9 @@ def train(arch_id: str, steps: int, batch: int, ckpt_dir: str | None,
     reg = obs.default_registry()
     arch = configs.get(arch_id)
     cfg = arch.make_config() if full else arch.make_smoke()
-    loss_fn = make_loss_fn(cfg, arch.family)
     batch_fn = make_batch_fn(cfg, arch.family, batch)
-
-    ocfg = opt_lib.OptimizerConfig(
-        lr=1e-3, total_steps=steps, warmup_steps=min(50, steps // 10 + 1),
-        rotation=rotations.RotationConfig.from_spec(rotation),
-    )
+    step_fn, ocfg = build_step(cfg, arch.family, steps, rotation,
+                               emit_deltas=live_loop is not None)
     key = jax.random.PRNGKey(seed)
     params = init_model(key, cfg, arch.family)
     state = ts.init_state(jax.random.fold_in(key, 1), params, ocfg)
@@ -184,19 +201,17 @@ def train(arch_id: str, steps: int, batch: int, ckpt_dir: str | None,
             start_step = latest
             print(f"[train] resumed from step {latest}")
 
-    step_fn = jax.jit(
-        ts.make_train_step(loss_fn, ocfg,
-                           emit_deltas=live_loop is not None),
-        donate_argnums=(0,))
-
     times: list[float] = []
     metrics_hist = []
     for i in range(start_step, steps):
         t0 = time.time()
         with reg.span("train.step"):
-            batch_data = next(pipe)
-            state, metrics = step_fn(state, *batch_data)
-            loss = float(metrics["loss"])   # blocks: the span covers compute
+            with obs.annotate("train.next_batch", step=i):
+                batch_data = next(pipe)
+            with obs.annotate("train.dispatch", step=i):
+                state, metrics = step_fn(state, *batch_data)
+            with obs.annotate("train.loss_read", step=i):
+                loss = float(metrics["loss"])   # blocks until the step ends
         if live_loop is not None:
             live_loop.on_step(metrics)
         dt = time.time() - t0

@@ -7,9 +7,14 @@ One lightweight subsystem watches every layer of the stack:
     default registry is DISABLED until ``obs.enable()`` and disabled
     instrumentation is near-free (shared null objects, no host syncs).
   * **spans** (``span``) — nestable, exception-safe timing blocks that can
-    ``sync`` on device values (block_until_ready-aware) and forward to
-    ``jax.profiler.TraceAnnotation`` under ``profile=True``; use
-    ``jax.named_scope`` for inside-jit stages.
+    ``sync`` on device values (block_until_ready-aware). Each also enters
+    ``annotate(name)``, a ``jax.profiler.TraceAnnotation``: it records
+    nothing unless a profiler session runs, and then lands in the trace on
+    the device clock. Hot paths call ``annotate`` alone (no clock, no
+    registry, no sync); ``SPAN_PREFIXES`` lists the program's span names'
+    prefixes for whoever reduces a trace. Use ``jax.named_scope`` for
+    inside-jit stages (``gcd`` in the GCD update, ``ivf.select`` in the
+    IVF search's candidate gather and top-k).
   * **exporters** — JSONL event log (``enable(jsonl=...)``), text
     snapshot (``report``), and the ``BENCH_*.json`` trajectory writer +
     validator (``write_bench``/``validate_bench``) that the benchmark
@@ -19,11 +24,16 @@ One lightweight subsystem watches every layer of the stack:
     regression, not just a latency blip.
 
 Who emits what: ``search.Engine`` (request latency p50/p99, bucket/pad
-waste, LUT hit rate, compile counts — via its always-on private registry
-behind ``stats()``), ``search.sharded`` (per-shard rows, shard-imbalance
+waste, LUT hit rate, compile counts, scheduled and real scan tile rows —
+via its always-on private registry behind ``stats()``; ``engine.*``
+annotations around submit, its rotate/LUT/dispatch stages, and collect),
+``serve.Frontend`` (``frontend.poll``/``frontend.serve`` annotations),
+``launch.train`` and ``data.pipeline`` (``train.*`` and ``pipeline.*``
+annotations around each step's batch wait, dispatch and loss read, and the
+prefetch worker's batch), ``search.sharded`` (per-shard rows, shard-imbalance
 gauge, named-scope scan/merge spans), ``index.maintain`` (refresh spans,
-delta norm, orthogonality drift), ``launch.train`` (step time, loss,
-rotation health), ``quant.kmeans`` (per-iteration distortion trace), and
+delta norm, orthogonality drift), ``launch.train --obs-log`` (step time,
+loss, rotation health), ``quant.kmeans`` (per-iteration distortion trace), and
 ``benchmarks/*`` (the BENCH trajectory).
 """
 from repro.obs.bench import (
@@ -40,7 +50,9 @@ from repro.obs.registry import (
     Distribution,
     Gauge,
     Registry,
+    SPAN_PREFIXES,
     Span,
+    annotate,
     counter,
     default_registry,
     disable,
@@ -61,7 +73,9 @@ __all__ = [
     "JsonlSink",
     "RecallProbe",
     "Registry",
+    "SPAN_PREFIXES",
     "Span",
+    "annotate",
     "bench_path",
     "counter",
     "default_registry",

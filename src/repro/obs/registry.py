@@ -25,10 +25,12 @@ Three design rules govern everything here:
 Spans nest: the recorded name is the dotted path of enclosing spans
 (``engine.search`` inside ``serve`` records ``serve.engine.search``), the
 stack is per-thread, and an exception inside the span still records the
-timing (with ``error=True``) and propagates. When the registry's
-``profile`` flag is on, each span also enters a
-``jax.profiler.TraceAnnotation`` so host spans line up with device ops in
-an XLA trace; ``trace(dir)`` wraps ``jax.profiler.trace`` the same way.
+timing (with ``error=True``) and propagates. Every span also enters
+``annotate(name)``, the one way the program marks a stretch of host work
+for the profiler: a ``jax.profiler.TraceAnnotation``, which records
+nothing unless a profiler session is running, so a span reaches the trace
+(on the device clock, beside the device ops) exactly when someone traces.
+Hot paths that need no registry timing call ``annotate`` directly.
 
 Registries are process-local. Metric CREATION (the get-or-create in
 ``counter``/``gauge``/``distribution``/``event``) is guarded by a lock, so
@@ -50,6 +52,17 @@ from typing import Any, Iterator
 import jax
 
 MetricKey = tuple[str, tuple[tuple[str, Any], ...]]
+
+#: name prefixes of the program's own spans and annotations, which tell
+#: them apart from JAX's own host events in a profiler trace
+SPAN_PREFIXES = ("train.", "pipeline.", "engine.", "frontend.", "churn.")
+
+
+def annotate(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span for the profiler's trace, with ``args`` as its metadata:
+    a context manager that reads no clock, writes to no registry and makes
+    no host sync; without a running profiler session it records nothing."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def _key(name: str, labels: dict[str, Any]) -> MetricKey:
@@ -241,9 +254,8 @@ class Span:
         stack = self._registry._span_stack()
         self.path = ".".join([*stack, self.name]) if stack else self.name
         stack.append(self.name)
-        if self._registry.profile:
-            self._annotation = jax.profiler.TraceAnnotation(self.path)
-            self._annotation.__enter__()
+        self._annotation = annotate(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -253,8 +265,7 @@ class Span:
                 _block_concrete(self._pending)
         finally:
             self.elapsed_ms = (time.perf_counter() - self._t0) * 1e3
-            if self._annotation is not None:
-                self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation.__exit__(exc_type, exc, tb)
             stack = self._registry._span_stack()
             if stack and stack[-1] == self.name:
                 stack.pop()
@@ -270,15 +281,12 @@ class Registry:
     """One process-local metrics namespace (see module docstring).
 
     ``window`` bounds both distribution sample windows and per-kind event
-    windows; ``profile=True`` forwards spans to
-    ``jax.profiler.TraceAnnotation``.
+    windows.
     """
 
-    def __init__(self, *, enabled: bool = True, window: int = 1024,
-                 profile: bool = False):
+    def __init__(self, *, enabled: bool = True, window: int = 1024):
         self.enabled = enabled
         self.window = max(1, window)
-        self.profile = profile
         self._metrics: dict[MetricKey, Any] = {}
         self._events: dict[str, collections.deque] = {}
         self._sinks: list = []
@@ -322,16 +330,6 @@ class Registry:
         if not self.enabled:
             return _NULL_SPAN
         return Span(self, name)
-
-    @contextlib.contextmanager
-    def trace(self, log_dir: str):
-        """``jax.profiler.trace`` for the enclosed block when profiling is
-        on (XLA-level device profile); a no-op otherwise."""
-        if not (self.enabled and self.profile):
-            yield
-            return
-        with jax.profiler.trace(log_dir):
-            yield
 
     # -- events -------------------------------------------------------------
     def event(self, kind: str, **fields) -> None:
@@ -404,11 +402,10 @@ def enabled() -> bool:
     return _default.enabled
 
 
-def enable(*, jsonl: str | None = None, profile: bool = False) -> Registry:
-    """Turn the global registry on (optionally attaching a JSONL event log
-    and/or ``jax.profiler`` span forwarding)."""
+def enable(*, jsonl: str | None = None) -> Registry:
+    """Turn the global registry on (optionally attaching a JSONL event
+    log)."""
     _default.enabled = True
-    _default.profile = profile
     if jsonl is not None:
         from repro.obs.export import JsonlSink
 

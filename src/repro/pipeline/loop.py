@@ -76,10 +76,9 @@ class LiveIndexLoop:
         submits a background compaction every ``compact_every`` rounds."""
         applied = len(self._buffer)
         if applied:
-            with self.obs.span("pipeline.refresh") as sp:
+            with obs.annotate("pipeline.refresh", deltas=applied):
                 for delta in self._buffer:
                     self.engine.refresh(delta)
-                sp.sync(self.engine.state)
             self._buffer.clear()
             if self.tracker is not None:
                 self.tracker.bump(applied)

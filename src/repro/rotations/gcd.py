@@ -114,17 +114,23 @@ class GCD:
 
     def update(self, state: GCDState, grad: jax.Array, lr: float | jax.Array,
                key: jax.Array) -> tuple[GCDState, base.GivensDelta]:
-        A = self._score(grad.astype(jnp.float32), state.R.astype(jnp.float32))
-        Ahat, acc, acc2 = _precondition(state, self._mask(A),
-                                        self.preconditioner)
-        pi, pj = self.select_pairs(Ahat, key)
-        theta = -jnp.asarray(lr, jnp.float32) * Ahat[pi, pj] / givens.SQRT2
-        delta = base.GivensDelta(
-            pi=pi, pj=pj, theta=theta,
-            overlapping=self.method.startswith("overlap"))
-        step = state.step + 1
-        R_new = base.maybe_reorthonormalize(
-            delta.apply(state.R), step, self.reorthonormalize_every)
+        # the named scope marks every op of the update (scoring,
+        # preconditioning, pair selection, the apply) in the compiled
+        # program's metadata, so a profile can be read by stage
+        with jax.named_scope("gcd"):
+            A = self._score(grad.astype(jnp.float32),
+                            state.R.astype(jnp.float32))
+            Ahat, acc, acc2 = _precondition(state, self._mask(A),
+                                            self.preconditioner)
+            pi, pj = self.select_pairs(Ahat, key)
+            theta = (-jnp.asarray(lr, jnp.float32) * Ahat[pi, pj]
+                     / givens.SQRT2)
+            delta = base.GivensDelta(
+                pi=pi, pj=pj, theta=theta,
+                overlapping=self.method.startswith("overlap"))
+            step = state.step + 1
+            R_new = base.maybe_reorthonormalize(
+                delta.apply(state.R), step, self.reorthonormalize_every)
         return GCDState(R=R_new, step=step, accum=acc, accum2=acc2), delta
 
     def _score(self, G: jax.Array, R: jax.Array) -> jax.Array:

@@ -42,7 +42,8 @@ the hot path shape-stable:
     current one.
   * **serving observability** — every request lands in a private, always-on
     ``repro.obs.Registry`` (latency distribution with p50/p95/p99, scanned
-    rows, bucket pad waste, LUT hit rate, compile counts) aggregated by
+    rows against the rows the scan was scheduled to read, bucket pad
+    waste, LUT hit rate, compile counts) aggregated by
     ``stats()``; an attached ``obs.RecallProbe`` replays a pinned query set
     through the serving path every N requests and gauges live recall@k.
   * **live refresh** — ``engine.refresh(delta)`` absorbs a rotation-learner
@@ -124,6 +125,7 @@ class Pending(NamedTuple):
     lut_misses: int
     t0: float                  # perf_counter at submit — latency anchor
     compiled_before: int | float
+    scheduled_rows: int        # rows the scan schedules for the b queries
 
 
 class Engine:
@@ -195,7 +197,7 @@ class Engine:
             name: self.obs.counter(f"engine.{name}")
             for name in ("requests", "queries", "compiles", "refreshes",
                          "lut_hits", "lut_misses", "lut_invalidations",
-                         "lut_evictions")}
+                         "lut_evictions", "tile_rows", "scheduled_rows")}
         self.probe = probe
         self._in_probe = False
 
@@ -261,6 +263,14 @@ class Engine:
             self._compiled[key] = jax.jit(
                 fn, donate_argnums=(1, 2) if self.donate else ())
         return self._compiled[key]
+
+    def _scheduled_rows(self, nprobe: int | None) -> int:
+        """Rows the scan schedules per query at this probe width (the
+        backend's ``scheduled_rows`` capability: whole tiles of every probed
+        list's window, holes included), 0 for a backend without a tile
+        schedule. Read from the state's statics: no host sync."""
+        fn = getattr(self.searcher, "scheduled_rows", None)
+        return 0 if fn is None else fn(self.state, nprobe)
 
     # -- per-query LUT cache -----------------------------------------------
     def _lut_key(self, row: np.ndarray) -> tuple:
@@ -346,29 +356,37 @@ class Engine:
         t0 = time.perf_counter()
 
         lut_hits = lut_misses = 0
-        if self._prepared_ok:
-            # the LUT cache keys on raw query bytes — the one place the
-            # batch must visit the host (dtype preserved, matching the
-            # plain path and direct searcher calls); rotation reads the
-            # original array, so a device-resident Q is not re-uploaded
-            Qnp = np.asarray(Q)
-            QR = self.searcher.rotate_queries(self.state, Q)
-            lut, lut_hits, lut_misses = self._gather_luts(Qnp, QR)
-            QR = jnp.pad(QR, ((0, pad), (0, 0)))
-            # pack-aware: cached host rows and all-miss device packs
-            # both pad up to the bucket and land on device
-            lut = _pad_lut(lut, pad)
-            res = self._prepared_fn(bucket, k, npb)(self.state, QR, lut)
-        else:
-            # plain path: never leaves the device
-            Qp = jnp.pad(jnp.asarray(Q), ((0, pad), (0, 0)))
-            res = self._plain_fn(bucket, k, npb)(self.state, Qp)
+        shape = dict(batch=b, bucket=bucket)
+        with obs.annotate("engine.submit", **shape):
+            if self._prepared_ok:
+                # the LUT cache keys on raw query bytes — the one place the
+                # batch must visit the host (dtype preserved, matching the
+                # plain path and direct searcher calls); rotation reads the
+                # original array, so a device-resident Q is not re-uploaded
+                Qnp = np.asarray(Q)
+                with obs.annotate("engine.rotate", **shape):
+                    QR = self.searcher.rotate_queries(self.state, Q)
+                with obs.annotate("engine.luts", **shape):
+                    lut, lut_hits, lut_misses = self._gather_luts(Qnp, QR)
+                with obs.annotate("engine.dispatch", **shape):
+                    QR = jnp.pad(QR, ((0, pad), (0, 0)))
+                    # pack-aware: cached host rows and all-miss device
+                    # packs both pad up to the bucket and land on device
+                    lut = _pad_lut(lut, pad)
+                    res = self._prepared_fn(bucket, k, npb)(self.state, QR,
+                                                            lut)
+            else:
+                # plain path: never leaves the device
+                with obs.annotate("engine.dispatch", **shape):
+                    Qp = jnp.pad(jnp.asarray(Q), ((0, pad), (0, 0)))
+                    res = self._plain_fn(bucket, k, npb)(self.state, Qp)
 
-        res = SearchResult(scores=res.scores[:b], ids=res.ids[:b],
-                           scanned=res.scanned[:b])
+            res = SearchResult(scores=res.scores[:b], ids=res.ids[:b],
+                               scanned=res.scanned[:b])
         return Pending(res=res, batch=b, bucket=bucket, k=k, nprobe=npb,
                        lut_hits=lut_hits, lut_misses=lut_misses, t0=t0,
-                       compiled_before=compiled_before)
+                       compiled_before=compiled_before,
+                       scheduled_rows=b * self._scheduled_rows(npb))
 
     def collect(self, pending: Pending) -> SearchResult:
         """Block on a ``submit``'s device work and account the request:
@@ -376,28 +394,33 @@ class Engine:
         ``search`` span used to measure), LUT hits/misses and the request
         event land here. Call once per Pending."""
         res = pending.res
-        leaves = [x for x in jax.tree_util.tree_leaves(res)
-                  if not isinstance(x, jax.core.Tracer)]
-        if leaves:
-            jax.block_until_ready(leaves)
-        latency_ms = (time.perf_counter() - pending.t0) * 1e3
+        with obs.annotate("engine.collect", batch=pending.batch,
+                          bucket=pending.bucket):
+            leaves = [x for x in jax.tree_util.tree_leaves(res)
+                      if not isinstance(x, jax.core.Tracer)]
+            if leaves:
+                jax.block_until_ready(leaves)
+            latency_ms = (time.perf_counter() - pending.t0) * 1e3
 
-        scanned_rows = float(np.mean(np.asarray(res.scanned)))
-        self._counters["requests"].inc()
-        self._counters["queries"].inc(pending.batch)
-        self._counters["lut_hits"].inc(pending.lut_hits)
-        self._counters["lut_misses"].inc(pending.lut_misses)
-        self._latency.observe(latency_ms)
-        self._scanned.observe(scanned_rows)
-        self._pad_waste.observe(
-            (pending.bucket - pending.batch) / pending.bucket)
-        self.obs.event(
-            "request", batch=pending.batch, bucket=pending.bucket,
-            k=pending.k, nprobe=pending.nprobe, latency_ms=latency_ms,
-            scanned_rows=scanned_rows, lut_hits=pending.lut_hits,
-            lut_misses=pending.lut_misses,
-            compiled=(self._counters["compiles"].value
-                      > pending.compiled_before))
+            scanned = np.asarray(res.scanned)
+            scanned_rows = float(np.mean(scanned))
+            self._counters["requests"].inc()
+            self._counters["queries"].inc(pending.batch)
+            self._counters["lut_hits"].inc(pending.lut_hits)
+            self._counters["lut_misses"].inc(pending.lut_misses)
+            self._counters["tile_rows"].inc(int(np.sum(scanned)))
+            self._counters["scheduled_rows"].inc(pending.scheduled_rows)
+            self._latency.observe(latency_ms)
+            self._scanned.observe(scanned_rows)
+            self._pad_waste.observe(
+                (pending.bucket - pending.batch) / pending.bucket)
+            self.obs.event(
+                "request", batch=pending.batch, bucket=pending.bucket,
+                k=pending.k, nprobe=pending.nprobe, latency_ms=latency_ms,
+                scanned_rows=scanned_rows, lut_hits=pending.lut_hits,
+                lut_misses=pending.lut_misses,
+                compiled=(self._counters["compiles"].value
+                          > pending.compiled_before))
 
         if self.probe is not None and not self._in_probe:
             self._in_probe = True
@@ -454,9 +477,8 @@ class Engine:
                     f"{dR.shape[-1]} but the live rotation is {n}x{n}")
         keep = (hasattr(self.searcher, "luts_refresh_invariant")
                 and self.searcher.luts_refresh_invariant(self.state, delta))
-        with self.obs.span("engine.refresh") as sp:
+        with obs.annotate("engine.refresh"):
             self.state = self.searcher.refresh(self.state, delta)
-            sp.sync(self.state)
         if not keep:
             self._luts.clear()
             self._epoch += 1
@@ -500,7 +522,9 @@ class Engine:
         Two scopes, in one place: **lifetime totals** — every counter key
         (``requests``, ``queries``, ``compiles``, ``executables``,
         ``refreshes``, ``lut_hits``, ``lut_misses``, and the
-        ``lut_hit_rate`` derived from them) counts since Engine
+        ``lut_hit_rate`` derived from them; ``tile_rows``, the rows of the
+        real list tiles the scan read, against ``scheduled_rows``, every
+        row it was scheduled to read) counts since Engine
         construction and never resets. **Window-scoped** — every latency /
         scanned-rows / pad-waste aggregate (mean, p50, p95, p99, max)
         covers only the retained request window: the last
@@ -523,6 +547,8 @@ class Engine:
             lut_evictions=c["lut_evictions"],
             lut_invalidations=c["lut_invalidations"],
             lut_epoch=self._epoch,
+            tile_rows=c["tile_rows"],
+            scheduled_rows=c["scheduled_rows"],
             window=dict(size=lat.get("window", 0),
                         capacity=self.history,
                         scope="latency/scanned/pad aggregates"),

@@ -74,6 +74,17 @@ class IVF:
             return state.max_blocks
         return state.index.max_list_blocks()
 
+    def scheduled_rows(self, state: ADCState, nprobe: int | None) -> int:
+        """Engine capability: rows the scan is scheduled to read per query —
+        ``max_blocks`` whole tiles of each probed list, sentinel hole
+        tiles of short lists included, plus the staging buffer's side
+        pass. ``SearchResult.scanned`` counts the real ones among them."""
+        rows = (self.effective_nprobe(state, nprobe)
+                * self._max_blocks(state) * state.index.block_size)
+        if state.staging is not None:
+            rows += state.staging.ids.size
+        return rows
+
     def prepare_state(self, state: ADCState) -> ADCState:
         """Bake derived statics into the state so it can be passed as a
         *traced* jit argument (the Engine does this once up front — the

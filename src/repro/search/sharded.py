@@ -156,7 +156,7 @@ def _merge_local_topk(scores: jax.Array, ids: jax.Array, k: int,
     """Inside shard_map: concatenate every shard's padded (b, k) run and
     re-top-k. Static shapes — (b, S·k) — whatever the per-shard pools.
     The ``jax.named_scope`` labels the gather+merge stage in the HLO, so an
-    XLA profile (``obs.Registry.trace``) separates collective time from
+    XLA profile (``jax.profiler.trace``) separates collective time from
     scan time at zero runtime cost."""
     with jax.named_scope("obs.gather_merge"):
         g_scores = jax.lax.all_gather(scores, axes, axis=1, tiled=True)
@@ -723,6 +723,17 @@ class IVFSharded:
         the shared coarse quantizer's list count)."""
         return min(state.nprobe if nprobe is None else nprobe,
                    state.num_lists)
+
+    def scheduled_rows(self, state: ShardedADCState,
+                       nprobe: int | None) -> int:
+        """Engine capability: rows the scan is scheduled to read per query,
+        over all shards (the replicated twin's ``scheduled_rows`` on each
+        shard's window and staging buffer)."""
+        rows = (self.effective_nprobe(state, nprobe) * state.max_blocks
+                * state.block_size * state.num_shards)
+        if state.staging is not None:
+            rows += state.staging.ids.size
+        return rows
 
     def prepare_state(self, state: ShardedADCState) -> ShardedADCState:
         """Engine capability: bake the probe window for a directly-

@@ -222,12 +222,12 @@ class Frontend:
         come back completed); when nothing was due, run one idle-slot
         churn maintenance tick instead. Returns the completed tickets."""
         done: list[Ticket] = []
-        for ns in self.namespaces:
-            while (batch := ns.queue.take(self.clock())):
-                done.extend(self._serve(ns, batch))
-            self.obs.gauge(f"serve.queue_depth.{ns.name}").set(ns.queue.depth)
-        if not done:
-            self._maintenance_tick()
+        with obs.annotate("frontend.poll"):
+            for ns in self.namespaces:
+                while (batch := ns.queue.take(self.clock())):
+                    done.extend(self._serve(ns, batch))
+            if not done:
+                self._maintenance_tick()
         return done
 
     def drain(self) -> list[Ticket]:
@@ -253,51 +253,58 @@ class Frontend:
     def _serve(self, ns: Namespace, batch: list[Ticket]) -> list[Ticket]:
         """Serve one flushed bucket: group by (k, nprobe), pick rungs for
         the adaptive groups, submit all groups (device work overlaps),
-        then collect in order and scatter rows back onto tickets."""
-        self._counters["flushes"].inc()
-        now = self.clock()
-        groups: dict[tuple, list[Ticket]] = {}
-        for t in batch:
-            key = (t.k, t.nprobe if t.nprobe is not None
-                   else (_ADAPTIVE if ns.adaptive else None))
-            groups.setdefault(key, []).append(t)
+        then collect in order and scatter rows back onto tickets. The
+        ``frontend.serve`` annotation carries the flush's size and sequence
+        number, which join the Engine's spans of one flush in a trace."""
+        flushes = self._counters["flushes"]
+        flushes.inc()
+        with obs.annotate("frontend.serve", size=len(batch),
+                          flush=flushes.value):
+            now = self.clock()
+            groups: dict[tuple, list[Ticket]] = {}
+            for t in batch:
+                key = (t.k, t.nprobe if t.nprobe is not None
+                       else (_ADAPTIVE if ns.adaptive else None))
+                groups.setdefault(key, []).append(t)
 
-        inflight = []
-        for (k, npkey), tickets in groups.items():
-            rung = None
-            if npkey is _ADAPTIVE:
-                budget = min(t.remaining_ms(now) for t in tickets)
-                bucket = ns.engine._bucket(len(tickets))
-                rung = ns.slo.choose(budget, bucket, backlog=ns.queue.depth)
-                if rung != ns.slo.ladder[-1]:
-                    self._counters["sheds"].inc()
-                npb = rung
-            else:
-                npb = npkey
-            Q = np.stack([t.query for t in tickets])
-            pending = ns.engine.submit(Q, k=k, nprobe=npb)
-            inflight.append((tickets, pending, rung))
-            self._counters["batches"].inc()
+            inflight = []
+            for (k, npkey), tickets in groups.items():
+                rung = None
+                if npkey is _ADAPTIVE:
+                    budget = min(t.remaining_ms(now) for t in tickets)
+                    bucket = ns.engine._bucket(len(tickets))
+                    rung = ns.slo.choose(budget, bucket,
+                                         backlog=ns.queue.depth)
+                    if rung != ns.slo.ladder[-1]:
+                        self._counters["sheds"].inc()
+                    npb = rung
+                else:
+                    npb = npkey
+                Q = np.stack([t.query for t in tickets])
+                pending = ns.engine.submit(Q, k=k, nprobe=npb)
+                inflight.append((tickets, pending, rung))
+                self._counters["batches"].inc()
 
-        done = []
-        for tickets, pending, rung in inflight:
-            res = ns.engine.collect(pending)
-            service_ms = (time.perf_counter() - pending.t0) * 1e3
-            if self._advance is not None:
-                # virtual time: queueing already elapsed on the virtual
-                # clock; fold the real measured service time in now
-                self._advance(service_ms * 1e-3)
-            completed = self.clock()
-            if rung is not None:
-                ns.slo.observe(pending.bucket, rung, service_ms)
-            for i, t in enumerate(tickets):
-                t.result = SearchResult(scores=res.scores[i], ids=res.ids[i],
-                                        scanned=res.scanned[i])
-                t.nprobe_served = pending.nprobe
-                t.completed = completed
-                done.append(t)
-            self._counters["served"].inc(len(tickets))
-        return done
+            done = []
+            for tickets, pending, rung in inflight:
+                res = ns.engine.collect(pending)
+                service_ms = (time.perf_counter() - pending.t0) * 1e3
+                if self._advance is not None:
+                    # virtual time: queueing already elapsed on the virtual
+                    # clock; fold the real measured service time in now
+                    self._advance(service_ms * 1e-3)
+                completed = self.clock()
+                if rung is not None:
+                    ns.slo.observe(pending.bucket, rung, service_ms)
+                for i, t in enumerate(tickets):
+                    t.result = SearchResult(scores=res.scores[i],
+                                            ids=res.ids[i],
+                                            scanned=res.scanned[i])
+                    t.nprobe_served = pending.nprobe
+                    t.completed = completed
+                    done.append(t)
+                self._counters["served"].inc(len(tickets))
+            return done
 
     # -- observability -----------------------------------------------------
     def stats(self) -> dict:

@@ -420,6 +420,54 @@ def test_engine_live_refresh_between_batches(data, states):
                                np.asarray(after.scores), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("nprobe", [None, 2, L])
+def test_engine_counts_tile_and_scheduled_rows(data, states, nprobe):
+    """``tile_rows``: the rows of the real list tiles each query's scan read
+    (the probed lists' padded lengths); ``scheduled_rows``: every row the
+    scan was scheduled to read (nprobe × max_blocks whole tiles a query,
+    sentinel hole tiles included). No refresh record is kept as a span."""
+    _, R, Q, _ = data
+    state = states["ivf"]
+    ix = state.index
+    engine = search.Engine(search.make("ivf"), state, k=10, nprobe=4,
+                           min_bucket=4)
+    Qnp = np.asarray(Q)[:5]
+    engine.search(Qnp, nprobe=nprobe)
+    npb = 4 if nprobe is None else nprobe
+    sizes = np.diff(np.asarray(ix.list_offsets))
+    coarse = (Qnp @ np.asarray(ix.R)) @ np.asarray(ix.centroids).T
+    probed = np.argsort(-coarse, axis=1)[:, :npb]
+    st = engine.stats()
+    assert st["tile_rows"] == int(sizes[probed].sum())
+    assert st["scheduled_rows"] == 5 * npb * state.max_blocks * BS
+    assert st["tile_rows"] <= st["scheduled_rows"]
+    engine.refresh(_delta(R))
+    assert not any(name.startswith("span.") for name in
+                   engine.obs.snapshot()["distributions"])
+
+
+def test_engine_counts_scheduled_rows_per_backend(data, states):
+    """The sharded twin schedules its window on every shard; a backend
+    without a tile schedule (exact) counts scanned rows only."""
+    _, _, Q, _ = data
+    Qnp = np.asarray(Q)[:6]
+    st_sh = states["ivf_sharded"]
+    sharded = search.Engine(search.make("ivf_sharded"), st_sh, k=10,
+                            nprobe=4, min_bucket=4)
+    res = sharded.search(Qnp)
+    got = sharded.stats()
+    assert got["tile_rows"] == int(np.sum(np.asarray(res.scanned)))
+    assert got["scheduled_rows"] == (6 * 4 * st_sh.max_blocks
+                                     * st_sh.block_size * st_sh.num_shards)
+    assert got["tile_rows"] <= got["scheduled_rows"]
+    exact = search.Engine(search.make("exact"), states["exact"], k=10,
+                          min_bucket=4)
+    res = exact.search(Qnp)
+    got = exact.stats()
+    assert got["tile_rows"] == int(np.sum(np.asarray(res.scanned)))
+    assert got["scheduled_rows"] == 0
+
+
 def test_engine_serves_sharded_backend(data, states):
     """The sharded family behind the Engine, unchanged: one compile per
     (bucket, k, nprobe), LUT cache live, refresh without recompiles."""

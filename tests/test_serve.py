@@ -305,6 +305,39 @@ def test_churn_ticks_in_idle_slots(corpus):
     assert 10_000 in np.asarray(t2.result.ids)
 
 
+def test_poll_spans_reach_a_profiler_trace(corpus, tmp_path):
+    """A flush under a profiler session: ``frontend.poll`` holds
+    ``frontend.serve`` (its size and sequence number), which holds the
+    Engine's submit and collect; a poll leaves no per-poll gauge."""
+    import glob
+
+    clk, fe, states = _frontend(corpus, admission_ms=2.0, max_admit=8)
+    Q = states["alpha"][1]
+    with jax.profiler.trace(str(tmp_path)):
+        for q in Q[:3]:
+            fe.submit("alpha", q)
+        clk.advance(0.01)
+        assert len(fe.poll()) == 3
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    evs = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+            dict(ev.stats))
+           for plane in jax.profiler.ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith(("frontend.", "engine."))]
+    by = {n: (s, e, a) for s, e, n, a in evs}
+    assert set(by) >= {"frontend.poll", "frontend.serve", "engine.submit",
+                       "engine.collect"}
+    assert by["frontend.serve"][2] == {"size": 3,
+                                       "flush": fe.stats()["flushes"]}
+    assert by["engine.submit"][2]["batch"] == 3
+    for inner, outer in (("frontend.serve", "frontend.poll"),
+                         ("engine.submit", "frontend.serve"),
+                         ("engine.collect", "frontend.serve")):
+        assert by[outer][0] <= by[inner][0] <= by[inner][1] <= by[outer][1]
+    assert not fe.obs.snapshot()["gauges"]
+
+
 def test_drain_and_ticket_errors(corpus):
     clk, fe, states = _frontend(corpus, admission_ms=1e6, max_admit=64)
     _, Q = states["alpha"]

@@ -205,6 +205,57 @@ def test_train_launcher_prefetch_resume_exact():
         assert np.isclose(hist_a[-1], hist_b[-1], rtol=1e-4), (hist_a, hist_b)
 
 
+def _host_spans(log_dir: str, prefixes: tuple) -> dict:
+    """host thread line -> [(start, end, name, args)] of a profiler trace's
+    events whose names start with ``prefixes`` (lines are keyed by their
+    position: threads may share a name)."""
+    import glob
+
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                    dict(ev.stats)) for ev in line.events
+                   if ev.name.startswith(prefixes)]
+            if evs:
+                out[(plane.name, i)] = evs
+    return out
+
+
+def test_train_spans_reach_a_profiler_trace(tmp_path):
+    """With a profiler session running, each step's batch wait, dispatch
+    and loss read land on the trainer's thread, and the prefetch worker's
+    batch (with its seed read inside) on the worker's — no switch, no
+    registry."""
+    from repro.launch import train as train_mod
+    train_mod.train("two-tower-retrieval", steps=2, batch=8, ckpt_dir=None,
+                    seed=3, log_every=100, prefetch=True)   # compile first
+    with jax.profiler.trace(str(tmp_path)):
+        train_mod.train("two-tower-retrieval", steps=3, batch=8,
+                        ckpt_dir=None, seed=3, log_every=100, prefetch=True)
+    threads = _host_spans(str(tmp_path), ("train.", "pipeline."))
+    main = next(t for t, evs in threads.items()
+                if any(n == "train.dispatch" for _, _, n, _ in evs))
+    names = [n for _, _, n, _ in threads[main]]
+    for stage in ("train.next_batch", "train.dispatch", "train.loss_read"):
+        assert names.count(stage) == 3, stage
+    assert [a["step"] for _, _, n, a in threads[main]
+            if n == "train.dispatch"] == [0, 1, 2]
+    workers = [t for t in threads if t != main]
+    assert workers                      # steps after the first prefetched
+    for t in workers:
+        produce = [(s, e) for s, e, n, _ in threads[t]
+                   if n == "pipeline.produce"]
+        seeds = [(s, e) for s, e, n, _ in threads[t] if n == "pipeline.seed"]
+        assert produce and len(seeds) == len(produce)
+        assert all(any(ps <= s and e <= pe for ps, pe in produce)
+                   for s, e in seeds)
+
+
 def test_update_with_deltas_matches_update():
     """``update_with_deltas`` is the same optimizer step plus the manifold
     deltas (the trainer→live-index sync contract): params bitwise equal to
