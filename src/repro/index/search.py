@@ -17,9 +17,12 @@ Per query batch (b, n):
 Because every list is padded to whole ``block_size`` tiles (ivf.pack), the
 probe window of each (query, list) pair is a fixed ``max_blocks`` tiles:
 shorter lists redirect their out-of-range tiles to the index's all-hole
-sentinel block, whose ids are −1 and therefore score −inf. Scan work per
-query is nprobe·max_blocks·block_size rows versus the corpus size for the
-flat scan — the recall/work trade-off is entirely in ``nprobe``.
+sentinel block, whose ids are −1 and therefore score −inf. So the scan
+schedules nprobe·max_blocks·block_size rows per query, and scores only the
+rows of the probed lists' own tiles (``SearchResult.scanned``): a step on
+the sentinel block stores −inf and skips its tile work in the kernel. The
+rows scored, against the corpus size for the flat scan, are the
+recall/work trade-off, and it is entirely in ``nprobe``.
 
 Device sharding: under an active mesh the candidate axis is annotated with
 the ``ivf`` rule table (sharding/rules.py) so XLA splits list scanning over
@@ -146,11 +149,13 @@ def _search_core(index: IVFPQIndex, QR: jax.Array, lut, *,
     )
 
     lut, scales = split_lut_pack(lut)
-    # holes/tombstones (id < 0) are masked to −inf inside the tile body;
-    # adding the finite coarse term afterwards cannot resurrect them
+    # holes/tombstones (id < 0) are masked to −inf inside the tile body, and
+    # steps on the sentinel block score −inf without the tile work; adding
+    # the finite coarse term afterwards cannot resurrect them
     res_scores = kops.ivf_adc(
         lut, index.codes, block_idx, block_query, scales, index.ids,
-        block_size=bs, use_kernel=use_kernels(use_kernel),
+        block_size=bs, hole_block=index.sentinel_block,
+        use_kernel=use_kernels(use_kernel),
     ).reshape(b, nprobe, max_blocks, bs)
     scores = res_scores + cscores[:, :, None, None]            # + coarse term
 

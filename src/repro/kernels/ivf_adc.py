@@ -26,6 +26,13 @@ never surface downstream (the caller's added coarse term is finite).
 One LUT row per step keeps the schedule fully general (any query mix); batch
 efficiency comes from the ~100× fewer tiles the probe selects, not from
 sharing tiles between queries.
+
+Hole steps: the search gives every (query, list) pair the same number of
+steps, and a short list's surplus steps point at the index's all-hole
+sentinel block. With ``hole_block`` (that block's static index) such a step
+is scheduled but not computed: it stores −inf and skips the one-hot build
+and the contraction. Consecutive hole steps name the same input blocks, so
+the pipeline issues no new DMA for them either.
 """
 from __future__ import annotations
 
@@ -40,44 +47,51 @@ from repro.kernels.adc_common import adc_tile_scores
 from repro.kernels.common import interpret_mode
 
 
-def _kernel(bi_ref, bq_ref, codes_ref, lut_ref, out_ref):
-    del bi_ref, bq_ref  # consumed by the index_maps
-    # shared family body with b = 1 (this step's query LUT): (1, bs)
-    scores = adc_tile_scores(codes_ref[...], lut_ref[...])
-    out_ref[...] = scores[None].astype(out_ref.dtype)
+def _make_kernel(quantized: bool, masked: bool, hole_block: int | None):
+    """Tile body of one scheduled step. Operands after the two prefetched
+    schedule arrays: codes tile, LUT row, then the (1, Dp, 2) scale row of
+    a quantized LUT and the (1, 1, bs) id tile, where present."""
 
+    def kernel(bi_ref, bq_ref, codes_ref, lut_ref, *refs):
+        out_ref = refs[-1]
+        scales_ref = refs[0] if quantized else None
+        ids_ref = refs[-2] if masked else None
 
-def _kernel_q(bi_ref, bq_ref, codes_ref, lut_ref, scales_ref, out_ref):
-    del bi_ref, bq_ref  # consumed by the index_maps
-    # quantized path: this step's LUT row rides in as int8/uint8 + its
-    # (1, Dp, 2) scale row; dequant happens in VMEM
-    scores = adc_tile_scores(codes_ref[...], lut_ref[...], scales_ref[...])
-    out_ref[...] = scores[None].astype(out_ref.dtype)
+        def score():
+            # shared family body with b = 1 (this step's query LUT): (1, bs);
+            # a quantized LUT row is dequantized in VMEM
+            scores = adc_tile_scores(
+                codes_ref[...], lut_ref[...],
+                scales_ref[...] if quantized else None)[None]
+            if masked:
+                # (1, 1, bs) id tile of this codes block: holes and
+                # tombstones → −inf
+                scores = jnp.where(ids_ref[...] >= 0, scores, -jnp.inf)
+            out_ref[...] = scores.astype(out_ref.dtype)
 
+        if hole_block is None:
+            score()
+            return
+        # a step scheduled on the all-hole block scores −inf without the
+        # one-hot build or the contraction
+        hole = bi_ref[pl.program_id(0)] == hole_block
+        pl.when(jnp.logical_not(hole))(score)
 
-def _kernel_m(bi_ref, bq_ref, codes_ref, lut_ref, ids_ref, out_ref):
-    del bi_ref, bq_ref  # consumed by the index_maps
-    scores = adc_tile_scores(codes_ref[...], lut_ref[...])[None]
-    # (1, 1, bs) id tile of this codes block: holes/tombstones → −inf
-    scores = jnp.where(ids_ref[...] >= 0, scores, -jnp.inf)
-    out_ref[...] = scores.astype(out_ref.dtype)
+        @pl.when(hole)
+        def _():
+            out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, out_ref.dtype)
 
-
-def _kernel_qm(bi_ref, bq_ref, codes_ref, lut_ref, scales_ref, ids_ref,
-               out_ref):
-    del bi_ref, bq_ref  # consumed by the index_maps
-    scores = adc_tile_scores(
-        codes_ref[...], lut_ref[...], scales_ref[...])[None]
-    scores = jnp.where(ids_ref[...] >= 0, scores, -jnp.inf)
-    out_ref[...] = scores.astype(out_ref.dtype)
+    return kernel
 
 
 #: schedule steps one pallas_call takes: its two int32 schedule arrays then
-#: use 256 KiB of SMEM
+#: use 256 KiB of SMEM. Hole steps count here too: they are scheduled, only
+#: their tile work is skipped
 SCHEDULE_STEPS = 32768
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_size", "hole_block", "interpret"))
 def ivf_adc(
     lut: jax.Array,
     codes: jax.Array,
@@ -87,6 +101,7 @@ def ivf_adc(
     ids: jax.Array | None = None,
     *,
     block_size: int = 128,
+    hole_block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """lut (b, Dp, K) float, codes (cap, Dp) int (cap % block_size == 0),
@@ -95,7 +110,9 @@ def ivf_adc(
     Residual depth rides in the Dp column dimension (Dp = M·D for RQ).
     With ``scales`` (b, Dp, 2) the lut is an int8/uint8 quantize_luts pack —
     the per-step LUT-row DMA moves 4× fewer bytes. With ``ids`` (cap,) the
-    tombstone mask applies inside the tile body (rows with id < 0 → −inf)."""
+    tombstone mask applies inside the tile body (rows with id < 0 → −inf).
+    Steps whose tile is block ``hole_block`` (the index's all-hole sentinel)
+    score −inf and skip the tile work."""
     b, Dp, K = lut.shape
     S = block_idx.shape[0]
     in_specs = [
@@ -103,9 +120,7 @@ def ivf_adc(
         pl.BlockSpec((1, Dp, K), lambda i, bi, bq: (bq[i], 0, 0)),
     ]
     operands = [codes, lut]
-    kernel = {(False, False): _kernel, (True, False): _kernel_q,
-              (False, True): _kernel_m, (True, True): _kernel_qm}[
-        (scales is not None, ids is not None)]
+    kernel = _make_kernel(scales is not None, ids is not None, hole_block)
     if scales is not None:
         in_specs.append(pl.BlockSpec((1, Dp, 2), lambda i, bi, bq: (bq[i], 0, 0)))
         operands.append(scales)

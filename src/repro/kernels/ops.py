@@ -126,18 +126,23 @@ def adc_batch(lut, codes, scales=None, *, use_kernel: bool = True):
     return ref.adc_batch_ref(lut, codes, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "use_kernel"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_size", "hole_block", "use_kernel"))
 def ivf_adc(lut, codes, block_idx, block_query, scales=None, ids=None, *,
-            block_size: int = 128, use_kernel: bool = True):
+            block_size: int = 128, hole_block: int | None = None,
+            use_kernel: bool = True):
     """Selected-block IVF-ADC scan: (b, D, K) LUTs × (cap, D) CSR codes ×
     (S,) block schedule -> (S, block_size) scores. ``scales`` (b, D, 2):
     quantized-LUT pack, the per-step LUT-row DMA shrinks 4×. ``ids`` (cap,):
-    tombstone mask — rows with id < 0 score −inf inside the tile body."""
+    tombstone mask — rows with id < 0 score −inf inside the tile body.
+    ``hole_block``: steps scheduled on this (all-hole) block score −inf and
+    the kernel skips their tile work."""
     if use_kernel:
         return _ivf.ivf_adc(lut, codes, block_idx, block_query, scales, ids,
-                            block_size=block_size)
+                            block_size=block_size, hole_block=hole_block)
     return ref.ivf_adc_ref(lut, codes, block_idx, block_query,
-                           block_size=block_size, scales=scales, ids=ids)
+                           block_size=block_size, scales=scales, ids=ids,
+                           hole_block=hole_block)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel",))

@@ -122,7 +122,8 @@ _REF_STEPS = 1024
 def ivf_adc_ref(lut: jax.Array, codes: jax.Array, block_idx: jax.Array,
                 block_query: jax.Array, *, block_size: int = 128,
                 scales: jax.Array | None = None,
-                ids: jax.Array | None = None) -> jax.Array:
+                ids: jax.Array | None = None,
+                hole_block: int | None = None) -> jax.Array:
     """Selected-block ADC scan. lut (b, D, K), codes (cap, D),
     block_idx/block_query (S,) -> (S, block_size): the scores of tile
     ``block_idx[s]`` of the CSR codes array under query ``block_query[s]``'s
@@ -131,7 +132,9 @@ def ivf_adc_ref(lut: jax.Array, codes: jax.Array, block_idx: jax.Array,
     ``scales`` (b, D, 2): quantized-LUT pack, dequantized up front.
     ``ids`` (cap,): tombstone mask — rows with id < 0 score −inf inside the
     scan, so holes and deletes never surface however the caller post-
-    processes (the added coarse term is finite and cannot resurrect them)."""
+    processes (the added coarse term is finite and cannot resurrect them).
+    ``hole_block``: every row of a step scheduled on this block scores
+    −inf, whatever its codes and ids (the kernel skips such steps)."""
     if scales is not None:
         lut = dequantize_luts(lut, scales)
 
@@ -146,6 +149,8 @@ def ivf_adc_ref(lut: jax.Array, codes: jax.Array, block_idx: jax.Array,
         out = jnp.sum(g, axis=-1).astype(jnp.float32)
         if ids is not None:
             out = jnp.where(ids[rows] >= 0, out, -jnp.inf)
+        if hole_block is not None:
+            out = jnp.where((bi == hole_block)[:, None], -jnp.inf, out)
         return out
 
     # the schedule is scanned in steps of _REF_STEPS: the TPU lowers the

@@ -68,6 +68,14 @@ KERNELS = {
                                                     interpret=False),
         [((B, DP, K), F32), ((ROWS, DP), U8), ((STEPS,), I32),
          ((STEPS,), I32), ((ROWS,), I32)]),
+    # the serving call: ids mask and the sentinel block's steps skipped, a
+    # scalar compare on the prefetched schedule steering ``pl.when``
+    "ivf_adc_masked_holes": (
+        lambda lut, c, bi, bq, ids: ivf_adc.ivf_adc(
+            lut, c, bi, bq, None, ids, hole_block=ROWS // 128 - 1,
+            interpret=False),
+        [((B, DP, K), F32), ((ROWS, DP), U8), ((STEPS,), I32),
+         ((STEPS,), I32), ((ROWS,), I32)]),
     "adc_lookup": (
         lambda lut, c: adc_lookup.adc_lookup(lut, c, interpret=False),
         [((B, DP, K), F32), ((ROWS, DP), U8)]),

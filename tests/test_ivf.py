@@ -8,6 +8,7 @@ Coverage demanded by ISSUE 1:
 plus CSR-layout invariants and add/remove maintenance.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -83,23 +84,49 @@ def test_search_kernel_matches_ref(index_and_data):
     np.testing.assert_array_equal(np.asarray(a.ids), np.asarray(b.ids))
 
 
-def test_ivf_adc_kernel_matches_ref():
+def _assert_hole_steps(got, plain, bi, hole_block):
+    """Steps on ``hole_block`` score exactly −inf; every other step is
+    bit-equal to the scan without ``hole_block``."""
+    hole = np.asarray(bi) == hole_block
+    assert hole.any() and (~hole).any()
+    assert np.all(np.isneginf(np.asarray(got)[hole]))
+    np.testing.assert_array_equal(np.asarray(got)[~hole],
+                                  np.asarray(plain)[~hole])
+
+
+@pytest.mark.parametrize("holes", [False, True])
+def test_ivf_adc_kernel_matches_ref(holes):
+    """Kernel == ref; with ``holes`` about half the steps sit on the
+    all-hole block and are skipped."""
     key = jax.random.PRNGKey(7)
     b, cap, bs, S = 5, 40 * 8, 8, 23
     lut = jax.random.normal(key, (b, D, K))
     codes = jax.random.randint(jax.random.PRNGKey(8), (cap, D), 0, K)
     bi = jax.random.randint(jax.random.PRNGKey(9), (S,), 0, cap // bs)
     bq = jax.random.randint(jax.random.PRNGKey(10), (S,), 0, b)
-    got = ops.ivf_adc(lut, codes, bi, bq, block_size=bs, use_kernel=True)
-    want = ref.ivf_adc_ref(lut, codes, bi, bq, block_size=bs)
+    hole_block = None
+    if holes:
+        hole_block = cap // bs - 1
+        bi = jnp.where(jax.random.bernoulli(jax.random.PRNGKey(15), 0.5,
+                                            (S,)), hole_block, bi)
+    got = ops.ivf_adc(lut, codes, bi, bq, block_size=bs,
+                      hole_block=hole_block, use_kernel=True)
+    want = ref.ivf_adc_ref(lut, codes, bi, bq, block_size=bs,
+                           hole_block=hole_block)
     assert got.shape == (S, bs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+    if holes:
+        plain = ops.ivf_adc(lut, codes, bi, bq, block_size=bs,
+                            use_kernel=True)
+        _assert_hole_steps(got, plain, bi, hole_block)
 
 
-def test_ivf_adc_long_schedule_in_pieces(monkeypatch):
+@pytest.mark.parametrize("holes", [False, True])
+def test_ivf_adc_long_schedule_in_pieces(monkeypatch, holes):
     """A schedule longer than one call's SMEM share is scanned in pieces
-    (the last one short) and gives the same scores as in one piece."""
+    (the last one short) and gives the same scores as in one piece; with
+    ``holes``, runs of hole steps straddle both piece boundaries."""
     from repro.kernels import ivf_adc as ivf_adc_mod
 
     monkeypatch.setattr(ivf_adc_mod, "SCHEDULE_STEPS", 8)
@@ -108,10 +135,65 @@ def test_ivf_adc_long_schedule_in_pieces(monkeypatch):
     codes = jax.random.randint(jax.random.PRNGKey(12), (cap, D), 0, K)
     bi = jax.random.randint(jax.random.PRNGKey(13), (S,), 0, cap // bs)
     bq = jax.random.randint(jax.random.PRNGKey(14), (S,), 0, b)
-    got = ops.ivf_adc(lut, codes, bi, bq, block_size=bs, use_kernel=True)
-    want = ref.ivf_adc_ref(lut, codes, bi, bq, block_size=bs)
+    hole_block = None
+    if holes:
+        hole_block = cap // bs - 1
+        bi = bi.at[5:11].set(hole_block).at[14:18].set(hole_block)
+    got = ops.ivf_adc(lut, codes, bi, bq, block_size=bs,
+                      hole_block=hole_block, use_kernel=True)
+    want = ref.ivf_adc_ref(lut, codes, bi, bq, block_size=bs,
+                           hole_block=hole_block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+    if holes:
+        plain = ops.ivf_adc(lut, codes, bi, bq, block_size=bs,
+                            use_kernel=True)
+        _assert_hole_steps(got, plain, bi, hole_block)
+
+
+def test_search_skewed_lists_skips_hole_steps(index_and_data, monkeypatch):
+    """Lists of very different lengths, so most scheduled tiles are the
+    sentinel block: the kernel, which skips them, answers as the jnp
+    reference does and as the kernel that scores them."""
+    index, X, Q = index_and_data
+    XR = X @ index.R
+    list_ids, codes = ivf.encode(XR, index.coarse, index.quantizer)
+    # three items in five crowd into list 0: it spans far more tiles than
+    # the others, whose surplus window steps all go to the sentinel block
+    crowd = np.random.RandomState(0).rand(N) < 0.6
+    skewed = ivf.pack(index.R, index.coarse, index.quantizer, codes,
+                      np.where(crowd, 0, np.asarray(list_ids)),
+                      jnp.arange(N, dtype=jnp.int32), block_size=BS)
+    max_blocks = skewed.max_list_blocks()
+    Qs = Q[:4]
+    # k past every probed live row: the answer is the whole candidate pool,
+    # so a hole row that scored anything but −inf would show in it
+    kw = dict(nprobe=4, k=2048, max_blocks=max_blocks)
+    got = search.search_fixed(skewed, Qs, use_kernel=True, **kw)
+    want = search.search_fixed(skewed, Qs, use_kernel=False, **kw)
+
+    real = ops.ivf_adc
+
+    def scores_hole_steps(*args, hole_block=None, **kwargs):
+        return real(*args, **kwargs)
+
+    # a fresh jit of search_fixed's body traces the scan without hole_block
+    monkeypatch.setattr(search.kops, "ivf_adc", scores_hole_steps)
+    unskipped = jax.jit(functools.partial(
+        search.search_fixed.__wrapped__, use_kernel=True, **kw))(skewed, Qs)
+
+    scheduled = Qs.shape[0] * kw["nprobe"] * max_blocks * BS
+    assert int(jnp.sum(got.scanned)) < 0.4 * scheduled  # mostly hole steps
+    assert np.all(np.asarray(got.scanned) < kw["k"])
+    for name in ("scores", "ids"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(unskipped, name)))
+    # the reference's sums round differently: near-ties may swap places
+    np.testing.assert_array_equal(np.sort(np.asarray(got.ids), axis=1),
+                                  np.sort(np.asarray(want.ids), axis=1))
+    np.testing.assert_allclose(np.asarray(got.scores),
+                               np.asarray(want.scores), rtol=1e-5,
+                               atol=1e-5)
 
 
 def test_refresh_subspace_step_is_exact(index_and_data):

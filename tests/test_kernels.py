@@ -148,9 +148,17 @@ def test_adc_lookup_int8_parity(dtype, Dp):
     assert agree >= 0.8
 
 
+def _hole_rows(block_idx, hole_block, bs):
+    """(S, bs) mask of the rows of steps scheduled on ``hole_block``."""
+    hole = np.asarray(block_idx) == hole_block
+    return np.broadcast_to(hole[:, None], (hole.size, bs))
+
+
+@pytest.mark.parametrize("holes", [False, True])
 @pytest.mark.parametrize("dtype", ["int8", "uint8"])
-def test_ivf_adc_int8_parity(dtype):
-    """Quantized probed scan: kernel == ref on the same pack."""
+def test_ivf_adc_int8_parity(dtype, holes):
+    """Quantized probed scan: kernel == ref on the same pack; with
+    ``holes`` every third step sits on the skipped all-hole block."""
     key = jax.random.PRNGKey(3)
     b, D, K, bs, nblocks = 3, 8, 16, 8, 12
     lut = jax.random.normal(key, (b, D, K))
@@ -158,12 +166,24 @@ def test_ivf_adc_int8_parity(dtype):
                                (bs * nblocks, D), 0, K)
     block_idx = jnp.arange(nblocks, dtype=jnp.int32)[::-1]
     block_query = jnp.asarray(np.resize(np.arange(b), nblocks), jnp.int32)
+    hole_block = nblocks - 1 if holes else None
+    if holes:
+        block_idx = jnp.where(jnp.arange(nblocks) % 3 == 1, hole_block,
+                              block_idx)
     qlut, scales = ops.quantize_luts(lut, dtype)
     got = np.asarray(ops.ivf_adc(qlut, codes, block_idx, block_query,
-                                 scales, block_size=bs))
+                                 scales, block_size=bs,
+                                 hole_block=hole_block))
     want = np.asarray(ref.ivf_adc_ref(qlut, codes, block_idx, block_query,
-                                      block_size=bs, scales=scales))
+                                      block_size=bs, scales=scales,
+                                      hole_block=hole_block))
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    if holes:
+        hole = _hole_rows(block_idx, hole_block, bs)
+        assert np.all(np.isneginf(got[hole]))
+        plain = np.asarray(ops.ivf_adc(qlut, codes, block_idx, block_query,
+                                       scales, block_size=bs))
+        np.testing.assert_array_equal(got[~hole], plain[~hole])
 
 
 @pytest.mark.parametrize("dtype", ["int8", "uint8"])
@@ -310,13 +330,15 @@ def test_adc_lookup_mask_property(N, D, K, b, quantized):
     np.testing.assert_array_equal(got[:, ~dead], plain[:, ~dead])
 
 
+@pytest.mark.parametrize("holes", [False, True])
 @given(nblocks=st.integers(2, 16), bs=st.sampled_from([8, 16]),
        b=st.integers(1, 4), quantized=st.booleans())
 @settings(deadline=None, max_examples=12)
-def test_ivf_adc_mask_property(nblocks, bs, b, quantized):
+def test_ivf_adc_mask_property(holes, nblocks, bs, b, quantized):
     """Masked probed scan: the ids operand rides the same block_idx
     prefetch as the codes tile — kernel == ref, masked rows −inf, live
-    rows bit-equal to the unmasked scan."""
+    rows bit-equal to the unmasked scan. With ``holes`` every other step
+    sits on the skipped all-hole block, whose rows are −inf too."""
     D, K = 4, 16
     key = jax.random.PRNGKey(nblocks * 17 + bs)
     lut = jax.random.normal(key, (b, D, K))
@@ -328,16 +350,24 @@ def test_ivf_adc_mask_property(nblocks, bs, b, quantized):
     block_idx = jnp.asarray(
         np.random.RandomState(nblocks).permutation(nblocks), jnp.int32)
     block_query = jnp.asarray(np.resize(np.arange(b), nblocks), jnp.int32)
+    hole_block = nblocks - 1 if holes else None
+    if holes:
+        block_idx = jnp.where(jnp.arange(nblocks) % 2 == 1, hole_block,
+                              block_idx)
     scales = None
     if quantized:
         lut, scales = ops.quantize_luts(lut, "int8")
     got = np.asarray(ops.ivf_adc(lut, codes, block_idx, block_query,
-                                 scales, ids, block_size=bs))
+                                 scales, ids, block_size=bs,
+                                 hole_block=hole_block))
     want = np.asarray(ref.ivf_adc_ref(lut, codes, block_idx, block_query,
-                                      block_size=bs, scales=scales, ids=ids))
+                                      block_size=bs, scales=scales, ids=ids,
+                                      hole_block=hole_block))
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
     rows = (np.asarray(block_idx)[:, None] * bs + np.arange(bs))
     dead = np.asarray(ids)[rows] < 0
+    if holes:
+        dead = dead | _hole_rows(block_idx, hole_block, bs)
     assert np.all(np.isneginf(got[dead]))
     plain = np.asarray(ops.ivf_adc(lut, codes, block_idx, block_query,
                                    scales, block_size=bs))
