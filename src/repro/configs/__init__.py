@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from repro.configs import (
+    deepseek_v2_lite,
     din,
     graphsage_reddit,
     grok_1_314b,
@@ -19,13 +20,15 @@ from repro.configs.base import ArchSpec, Shape
 _MODULES = [
     qwen1_5_4b, olmo_1b, nemotron_4_340b, grok_1_314b, llama4_maverick_400b,
     graphsage_reddit, wide_deep, two_tower_retrieval, mind, din,
-    paper_twotower,
+    paper_twotower, deepseek_v2_lite,
 ]
 
 REGISTRY: dict[str, ArchSpec] = {m.ARCH.arch_id: m.ARCH for m in _MODULES}
 
-# The 10 assigned architectures (paper-twotower is extra, not in the grid).
-ASSIGNED = [a for a in REGISTRY if a != "paper-twotower"]
+# The 10 assigned architectures (paper-twotower and deepseek-v2-lite are
+# extra, not in the dry-run grid).
+ASSIGNED = [a for a in REGISTRY
+            if a not in ("paper-twotower", "deepseek-v2-lite")]
 
 
 def get(arch_id: str) -> ArchSpec:

@@ -13,7 +13,8 @@ def make_config() -> TransformerConfig:
         name="grok-1-314b", num_layers=64, d_model=6144, num_heads=48,
         num_kv_heads=8, head_dim=128, d_ff=32768, vocab_size=131072,
         activation="gelu", use_glu=True, qkv_bias=False, norm="rmsnorm",
-        moe=MoEConfig(num_experts=8, top_k=2), rules="lm_base",
+        moe=MoEConfig(num_experts=8, top_k=2, aux_alpha=0.01 / 64),
+        rules="lm_base",
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
     )
 
@@ -23,7 +24,7 @@ def make_smoke() -> TransformerConfig:
         name="grok-1-smoke", num_layers=2, d_model=64, num_heads=8,
         num_kv_heads=2, head_dim=8, d_ff=128, vocab_size=300,
         activation="gelu", use_glu=True, norm="rmsnorm",
-        moe=MoEConfig(num_experts=4, top_k=2),
+        moe=MoEConfig(num_experts=4, top_k=2, aux_alpha=0.01 / 2),
         dtype=jnp.float32, param_dtype=jnp.float32, q_chunk=16, xent_chunk=32,
     )
 

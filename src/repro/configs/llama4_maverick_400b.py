@@ -20,7 +20,8 @@ def make_config() -> TransformerConfig:
         name="llama4-maverick-400b-a17b", num_layers=48, d_model=5120,
         num_heads=40, num_kv_heads=8, head_dim=128, d_ff=8192,
         vocab_size=202048, activation="silu", use_glu=True, qkv_bias=False,
-        norm="rmsnorm", moe=MoEConfig(num_experts=128, top_k=1),
+        norm="rmsnorm", moe=MoEConfig(num_experts=128, top_k=1,
+                                           aux_alpha=0.01 / 48),
         moe_every=2, rules="lm_attn_dp",
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
     )
@@ -31,7 +32,8 @@ def make_smoke() -> TransformerConfig:
         name="llama4-maverick-smoke", num_layers=4, d_model=64, num_heads=4,
         num_kv_heads=2, head_dim=16, d_ff=128, vocab_size=301,
         activation="silu", use_glu=True, norm="rmsnorm",
-        moe=MoEConfig(num_experts=8, top_k=1), moe_every=2,
+        moe=MoEConfig(num_experts=8, top_k=1, aux_alpha=0.01 / 4),
+        moe_every=2,
         dtype=jnp.float32, param_dtype=jnp.float32, q_chunk=16, xent_chunk=32,
     )
 

@@ -16,6 +16,10 @@ dequantizes the cache into dense form:
 
 Memory: head_dim·2 bytes → D bytes per vector (e.g. 128·2B → 16B at D=16,
 a 16× cut) — this is what makes the 500k-context decode cells feasible.
+
+Latent attention (MLA) caches one shared latent per token instead of
+per-head K/V; ``latent_distortion`` is the training term for a PQ over that
+latent (one rotation ``rot_c`` and one codebook per layer).
 """
 from __future__ import annotations
 
@@ -219,3 +223,28 @@ def kv_distortion(params: KVQuantParams, k: jax.Array, v: jax.Array) -> jax.Arra
     dk = params.quant_k.distortion(kf @ params.rot_k)
     dv = params.quant_v.distortion(vf @ params.rot_v)
     return dk + dv
+
+
+def latent_distortion(rot_c: jax.Array, cb_c: jax.Array,
+                      c: jax.Array) -> jax.Array:
+    """The Eq. (1) second term on a latent-attention stream (MLA): the PQ
+    index layer over the shared latent ``c`` (..., r) that every head's K/V
+    is up-projected from — one rotation ``rot_c`` ∈ SO(r) and one codebook
+    ``cb_c`` (D, K, r/D) per layer, (1/m)‖cR − φ(cR)‖² over the m vectors.
+    Drives codebook SGD and supplies ∇_rot_c for GCD."""
+    z = c.reshape(-1, c.shape[-1]).astype(jnp.float32) @ rot_c
+    pq = quant.PQ(cb_c)
+    # nearest codewords in row chunks: the (rows, D, K) distance table of
+    # all 16k latents of a train step would take 1 GiB at D=64, K=256
+    m, chunk = z.shape[0], LATENT_ASSIGN_ROWS
+    zs = jax.lax.stop_gradient(z)
+    if m > chunk and m % chunk == 0:
+        codes = jax.lax.map(pq.encode, zs.reshape(m // chunk, chunk, -1))
+        codes = codes.reshape(m, -1)
+    else:
+        codes = pq.encode(zs)
+    return pq.distortion(z, codes)
+
+
+#: rows per chunk of the latent term's nearest-codeword search
+LATENT_ASSIGN_ROWS = 2048

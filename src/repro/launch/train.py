@@ -54,7 +54,8 @@ def make_batch_fn(cfg, family: str, batch: int):
     """Family-specific synthetic batch maker: key -> tuple of arrays."""
     if family == "lm":
         def f(key):
-            return synthetic.lm_batch(key, batch, 128, cfg.vocab_size)
+            return synthetic.lm_batch(key, batch, cfg.train_seq_len,
+                                      cfg.vocab_size)
         return f
     if family == "gnn":
         from repro.data import graph as graph_lib
@@ -91,7 +92,7 @@ def make_batch_fn(cfg, family: str, batch: int):
 
 def make_loss_fn(cfg, family: str):
     if family == "lm":
-        return lambda p, tok, lab: tfm.forward_train(p, tok, lab, cfg)
+        return lambda p, tok, lab: tfm.forward_train_stats(p, tok, lab, cfg)
     if family == "gnn":
         return lambda p, h0, h1, h2, lab: gnn.loss_minibatch(
             p, [h0, h1, h2], lab, cfg)
@@ -132,7 +133,8 @@ def build_step(cfg, family: str, steps: int, rotation: str, *,
     )
     step_fn = jax.jit(
         ts.make_train_step(make_loss_fn(cfg, family), ocfg,
-                           emit_deltas=emit_deltas),
+                           emit_deltas=emit_deltas,
+                           has_aux=family == "lm"),
         donate_argnums=(0,))
     return step_fn, ocfg
 
@@ -222,6 +224,10 @@ def train(arch_id: str, steps: int, batch: int, ckpt_dir: str | None,
             reg.gauge("train.loss").set(loss)   # eq1 term included for
             reg.gauge("train.grad_norm").set(   # quantization-aware archs
                 float(metrics["grad_norm"]))
+            for name in ("moe.local_tokens", "moe.max_expert_load",
+                         "moe.dropped"):            # expert-layer counters
+                if name in metrics:
+                    reg.gauge(name).set(float(metrics[name]))
         if len(times) > 8:
             med = statistics.median(times[-64:])
             if dt > watchdog_factor * med:

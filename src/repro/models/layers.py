@@ -8,10 +8,12 @@ a few GB/device at the production shapes (see DESIGN.md §3).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows finite
 
@@ -90,13 +92,47 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2's yarn_get_mscale)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (Peng et al. 2023, as DeepSeek-V2 sets them):
+    the fast dimensions keep base frequencies, the slow ones are divided by
+    ``factor``, with a linear ramp between the correction dimensions of
+    ``beta_fast`` and ``beta_slow`` rotations. ``factor`` 1 is plain RoPE."""
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor <= 1.0:
+        return base.astype(np.float32)
+
+    def corr(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp              # 1 where the base frequency is kept
+    freqs = base / factor * (1.0 - keep) + base * keep
+    return freqs.astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               *, freqs=None, mscale: float = 1.0) -> jax.Array:
+    """x: (B, S, H, hd); positions: (B, S) int32. ``freqs`` (hd/2,) replaces
+    the plain frequencies of ``theta`` (YaRN); cos and sin are scaled by
+    ``mscale``."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)  # (hd/2,)
+    if freqs is None:
+        freqs = rope_frequencies(hd, theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    cos = (jnp.cos(angles) * mscale)[:, :, None, :]
+    sin = (jnp.sin(angles) * mscale)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -106,24 +142,27 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> ja
 # Blockwise causal attention (training / prefill)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("q_chunk", "causal"))
+@functools.partial(jax.jit, static_argnames=("q_chunk", "causal", "scale"))
 def blockwise_attention(
     q: jax.Array,  # (B, S, Hq, hd)
     k: jax.Array,  # (B, S, Hkv, hd)
-    v: jax.Array,  # (B, S, Hkv, hd)
+    v: jax.Array,  # (B, S, Hkv, hv)
     *,
     q_chunk: int = 256,
     causal: bool = True,
+    scale: float | None = None,
 ) -> jax.Array:
     """Causal GQA attention, scanned over query chunks with f32 softmax.
 
     Peak temp is (B, Hkv, rep, q_chunk, S) f32 per chunk instead of the full
-    (B, H, S, S) score tensor.
+    (B, H, S, S) score tensor. ``scale`` defaults to hd^-0.5; values may
+    have their own width hv (MLA). Returns (B, S, Hq, hv).
     """
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
+    hv = v.shape[-1]
     rep = Hq // Hkv
-    scale = hd**-0.5
+    scale = hd**-0.5 if scale is None else scale
     q_chunk = min(q_chunk, S)
     assert S % q_chunk == 0, f"seq {S} must be divisible by q_chunk {q_chunk}"
     nq = S // q_chunk
@@ -155,8 +194,8 @@ def blockwise_attention(
 
     outs = jax.lax.map(lambda args: one_chunk(*args), (jnp.arange(nq), qg))
     # (nq, B, Hkv, rep, q_chunk, hd) -> (B, S, Hq, hd)
-    outs = outs.transpose(1, 2, 3, 0, 4, 5).reshape(B, Hkv, rep, S, hd)
-    return outs.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, hd)
+    outs = outs.transpose(1, 2, 3, 0, 4, 5).reshape(B, Hkv, rep, S, hv)
+    return outs.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, hv)
 
 
 def decode_attention(

@@ -1,23 +1,34 @@
 """Decoder-only transformer LM substrate: dense / GQA / MoE variants.
 
-One config covers all five assigned LM architectures (qwen1.5-4b, olmo-1b,
-nemotron-4-340b, grok-1, llama4-maverick). Layers are scanned (params carry a
-leading layer axis) and rematerialized, so the HLO stays compact at 96 layers
-and activation memory is O(1) in depth.
+One config covers the five assigned LM architectures (qwen1.5-4b, olmo-1b,
+nemotron-4-340b, grok-1, llama4-maverick) and DeepSeek-V2-Lite. Layers are
+scanned (params carry a leading layer axis) and rematerialized, so the HLO
+stays compact at 96 layers and activation memory is O(1) in depth.
 
 ``moe_every=2`` (llama4-style interleaving) scans over two-layer super-blocks
 — sublayer "a" dense, sublayer "b" MoE — because lax.scan needs homogeneous
-per-step params.
+per-step params. ``first_dense_layers`` leading dense layers (DeepSeek's
+``first_k_dense_replace``) run before the scan, unrolled, with their own
+params (``dense_layers``).
+
+``mla`` switches attention to DeepSeek-V2's multi-head latent attention
+(arXiv 2405.04434 §2.1): keys and values come from one 512-wide latent
+``c_kv`` per token (down-projection, RMSNorm, per-head up-projections) plus
+one decoupled RoPE key shared by all heads, with YaRN frequencies. With
+``kv_quant`` set, the PQ index layer sits on that shared latent (one
+rotation ``rot_c`` and one codebook per layer) instead of per-head K/V.
 
 Paths:
   forward_train   tokens → mean xent loss (+ MoE aux, + optional KV-PQ
                   distortion term — the paper's Eq. 1 second term applied to
-                  the KV stream)
+                  the KV stream); ``forward_train_stats`` also returns the
+                  expert layers' routing counters
   serve_prefill   tokens → last-token logits + KV cache (dense or PQ codes)
   serve_decode    one token in, one token out, cache updated in place
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, NamedTuple
 
@@ -28,6 +39,36 @@ from repro.core import kv_quant
 from repro.models import layers, moe as moe_lib, param
 from repro.models.param import ParamSpec
 from repro.sharding import rules as sh
+
+
+class MLAConfig(NamedTuple):
+    """Multi-head latent attention without a query LoRA, with YaRN RoPE
+    (DeepSeek-V2 §2.1 and its config's ``rope_scaling``)."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_factor: float = 1.0         # YaRN scaling factor (1: plain RoPE)
+    original_max_pos: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        m = layers.yarn_mscale(self.rope_factor, self.mscale_all_dim)
+        return self.q_head_dim ** -0.5 * m * m
+
+    @property
+    def rope_mscale(self) -> float:
+        return (layers.yarn_mscale(self.rope_factor, self.mscale)
+                / layers.yarn_mscale(self.rope_factor, self.mscale_all_dim))
 
 
 class TransformerConfig(NamedTuple):
@@ -46,6 +87,9 @@ class TransformerConfig(NamedTuple):
     rope_theta: float = 10000.0
     moe: moe_lib.MoEConfig | None = None
     moe_every: int = 1               # 2 → dense/MoE interleave (llama4)
+    first_dense_layers: int = 0      # leading dense layers (DeepSeek)
+    mla: MLAConfig | None = None     # latent attention (DeepSeek-V2)
+    train_seq_len: int = 128         # the trainer's LM sequence length
     kv_quant: kv_quant.KVQuantConfig | None = None
     train_kv_quant: bool = False     # add KV distortion term to the train loss
     dtype: Any = jnp.bfloat16        # activation dtype
@@ -53,8 +97,8 @@ class TransformerConfig(NamedTuple):
     q_chunk: int = 256
     xent_chunk: int = 8192
     moe_chunk: int = 0               # >0: serve-path MoE processed in token
-    #                                  chunks (bounds the E·C dispatch buffers
-    #                                  at 1M-token prefill)
+    #                                  chunks (bounds the (T·k, d) routed-row
+    #                                  buffers at 1M-token prefill)
     remat: bool = True
     scan_groups: int = 1             # two-level layer scan: only every
     #                                  (scan_len/scan_groups)-th boundary is
@@ -72,17 +116,28 @@ class TransformerConfig(NamedTuple):
 
     @property
     def scan_len(self) -> int:
-        return self.num_layers // (2 if self.interleaved else 1)
+        return ((self.num_layers - self.first_dense_layers)
+                // (2 if self.interleaved else 1))
 
 
 # ---------------------------------------------------------------------------
 # Parameter specs
 # ---------------------------------------------------------------------------
 
-def _sublayer_specs(cfg: TransformerConfig, L: int, moe_on: bool):
+def _attn_specs(cfg: TransformerConfig, L: int):
     d = cfg.d_model
+    if cfg.mla is not None:
+        m, H = cfg.mla, cfg.num_heads
+        r = m.kv_lora_rank
+        return {
+            "wq": ParamSpec((L, d, H * m.q_head_dim), ("layers", "w_embed", "w_heads")),
+            "wkv_a": ParamSpec((L, d, r + m.qk_rope_head_dim), ("layers", "w_embed", None)),
+            "kv_norm": ParamSpec((L, r), ("layers", None), init="ones"),
+            "wkv_b": ParamSpec((L, r, H * (m.qk_nope_head_dim + m.v_head_dim)),
+                               ("layers", None, "w_heads")),
+            "wo": ParamSpec((L, H * m.v_head_dim, d), ("layers", "w_heads", "w_embed")),
+        }
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    f = cfg.d_ff
     attn = {
         "wq": ParamSpec((L, d, Hq * hd), ("layers", "w_embed", "w_heads")),
         "wk": ParamSpec((L, d, Hkv * hd), ("layers", "w_embed", "w_kv_heads")),
@@ -93,16 +148,27 @@ def _sublayer_specs(cfg: TransformerConfig, L: int, moe_on: bool):
         attn["bq"] = ParamSpec((L, Hq * hd), ("layers", "w_heads"), init="zeros")
         attn["bk"] = ParamSpec((L, Hkv * hd), ("layers", "w_kv_heads"), init="zeros")
         attn["bv"] = ParamSpec((L, Hkv * hd), ("layers", "w_kv_heads"), init="zeros")
+    return attn
 
+
+def _sublayer_specs(cfg: TransformerConfig, L: int, moe_on: bool):
+    d = cfg.d_model
+    f = cfg.d_ff
     if moe_on:
-        E = cfg.moe.num_experts
+        m = cfg.moe
+        E, Eh, fe = m.num_experts, m.held, m.d_ff or f
         ffn = {
             "router": ParamSpec((L, d, E), ("layers", "w_embed", None)),
-            "wi": ParamSpec((L, E, d, f), ("layers", "w_experts", "w_embed", "w_expert_mlp")),
-            "wo": ParamSpec((L, E, f, d), ("layers", "w_experts", "w_expert_mlp", "w_embed")),
+            "wi": ParamSpec((L, Eh, d, fe), ("layers", "w_experts", "w_embed", "w_expert_mlp")),
+            "wo": ParamSpec((L, Eh, fe, d), ("layers", "w_experts", "w_expert_mlp", "w_embed")),
         }
         if cfg.use_glu:
-            ffn["wg"] = ParamSpec((L, E, d, f), ("layers", "w_experts", "w_embed", "w_expert_mlp"))
+            ffn["wg"] = ParamSpec((L, Eh, d, fe), ("layers", "w_experts", "w_embed", "w_expert_mlp"))
+        if m.shared_ff:
+            fs = m.shared_ff
+            ffn["shared_wi"] = ParamSpec((L, d, fs), ("layers", "w_embed", "w_mlp"))
+            ffn["shared_wg"] = ParamSpec((L, d, fs), ("layers", "w_embed", "w_mlp"))
+            ffn["shared_wo"] = ParamSpec((L, fs, d), ("layers", "w_mlp", "w_embed"))
     else:
         ffn = {
             "wi": ParamSpec((L, d, f), ("layers", "w_embed", "w_mlp")),
@@ -111,7 +177,7 @@ def _sublayer_specs(cfg: TransformerConfig, L: int, moe_on: bool):
         if cfg.use_glu:
             ffn["wg"] = ParamSpec((L, d, f), ("layers", "w_embed", "w_mlp"))
 
-    out = {"attn": attn, "ffn": ffn}
+    out = {"attn": _attn_specs(cfg, L), "ffn": ffn}
     if cfg.norm == "rmsnorm":
         out["ln1"] = ParamSpec((L, d), ("layers", None), init="ones")
         out["ln2"] = ParamSpec((L, d), ("layers", None), init="ones")
@@ -128,19 +194,30 @@ def param_specs(cfg: TransformerConfig):
             "b": _sublayer_specs(cfg, Lb, moe_on=True),
         }
     else:
-        layers_spec = _sublayer_specs(cfg, L, moe_on=cfg.moe is not None)
+        layers_spec = _sublayer_specs(cfg, L - cfg.first_dense_layers,
+                                      moe_on=cfg.moe is not None)
 
     specs = {
         "embed": ParamSpec((V, d), ("w_vocab", "w_embed"), scale=1.0),
         "head": ParamSpec((V, d), ("w_vocab", "w_embed")),
         "layers": layers_spec,
     }
+    if cfg.first_dense_layers:
+        specs["dense_layers"] = _sublayer_specs(cfg, cfg.first_dense_layers,
+                                                moe_on=False)
     if cfg.norm == "rmsnorm":
         specs["ln_f"] = ParamSpec((d,), (None,), init="ones")
 
     if cfg.kv_quant is not None:
         kq = cfg.kv_quant
         D, K, sub = kq.num_subspaces, kq.num_codewords, kq.sub
+        if cfg.mla is not None:   # one latent stream per layer
+            n = kq.head_dim
+            specs["kvq"] = {
+                "rot_c": ParamSpec((L, n, n), ("layers", "rot_in", "rot_out"), init="eye"),
+                "cb_c": ParamSpec((L, D, K, sub), ("layers", "pq_dim", "pq_code", "pq_sub"), scale=0.02),
+            }
+            return specs
         specs["kvq"] = {
             "rot_k": ParamSpec((L, hd, hd), ("layers", "rot_in", "rot_out"), init="eye"),
             "rot_v": ParamSpec((L, hd, hd), ("layers", "rot_in", "rot_out"), init="eye"),
@@ -156,10 +233,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig):
 
 def _kvq_scan_tree(params, cfg: TransformerConfig):
     """KV-quant params reshaped for the layer scan: leading scan_len (and a
-    sublayer pair axis when interleaved). None when disabled."""
+    sublayer pair axis when interleaved), the leading dense layers' rows
+    left out. None when disabled."""
     if "kvq" not in params:
         return None
     kvq = params["kvq"]
+    if cfg.first_dense_layers:
+        kvq = jax.tree.map(lambda a: a[cfg.first_dense_layers:], kvq)
     if cfg.interleaved:
         Lb = cfg.scan_len
         return jax.tree.map(lambda a: a.reshape(Lb, 2, *a.shape[1:]), kvq)
@@ -203,35 +283,63 @@ def _qkv(lp, h, cfg: TransformerConfig, positions):
     return q, k, v
 
 
+def _mla_qkv(lp, h, cfg: TransformerConfig, positions):
+    """MLA projections (DeepSeek-V2 §2.1.2–2.1.3, no query LoRA): q from h;
+    the latent c = RMSNorm(h·W_DKV) and the shared RoPE key from one
+    down-projection; per-head k_nope and v from c·W_UKV. Returns
+    q, k (B, S, H, nope + rope), v (B, S, H, v_head_dim) and c (B, S, r)."""
+    m, H = cfg.mla, cfg.num_heads
+    B, S, _ = h.shape
+    dn, dr, r = m.qk_nope_head_dim, m.qk_rope_head_dim, m.kv_lora_rank
+    a = lp["attn"]
+    q = (h @ a["wq"].astype(h.dtype)).reshape(B, S, H, dn + dr)
+    kv_a = h @ a["wkv_a"].astype(h.dtype)
+    c = layers.rms_norm(kv_a[..., :r], a["kv_norm"])
+    kv = (c @ a["wkv_b"].astype(h.dtype)).reshape(B, S, H, dn + m.v_head_dim)
+    freqs = layers.yarn_frequencies(dr, cfg.rope_theta, m.rope_factor,
+                                    m.original_max_pos, m.beta_fast,
+                                    m.beta_slow)
+    q_pe = layers.apply_rope(q[..., dn:], positions, freqs=freqs,
+                             mscale=m.rope_mscale)
+    k_pe = layers.apply_rope(kv_a[:, :, None, r:], positions, freqs=freqs,
+                             mscale=m.rope_mscale)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_pe, (B, S, H, dr))], axis=-1)
+    return q, k, kv[..., dn:], c
+
+
 def _ffn(lp, h, cfg: TransformerConfig, moe_on: bool):
-    """Dense MLP or MoE on (B, S, d). Returns (out, aux_loss)."""
+    """Dense MLP or MoE on (B, S, d). Returns (out, aux_loss, stats)."""
     rt = cfg.rule_table
     if moe_on:
         B, S, d = h.shape
         T = B * S
+        f = lp["ffn"]
+        shared = ((f["shared_wi"], f["shared_wg"], f["shared_wo"])
+                  if "shared_wi" in f else None)
 
-        def run_moe(tokens):
+        def run_moe(tokens, seq_len):
             return moe_lib.moe_block(
-                tokens,
-                lp["ffn"]["router"].astype(jnp.float32),
-                lp["ffn"]["wi"],
-                lp["ffn"].get("wg"),
-                lp["ffn"]["wo"],
-                cfg.moe,
-                activation=cfg.activation,
-                rule_table=rt,
-            )
+                tokens, f["router"].astype(jnp.float32), f["wi"],
+                f.get("wg"), f["wo"], cfg.moe, activation=cfg.activation,
+                rule_table=rt, seq_len=seq_len, shared=shared)
 
         flat = h.reshape(T, d)
         if cfg.moe_chunk and T > cfg.moe_chunk:
             assert T % cfg.moe_chunk == 0
             nc = T // cfg.moe_chunk
-            out, aux = jax.lax.map(run_moe, flat.reshape(nc, cfg.moe_chunk, d))
+            out, aux, st = jax.lax.map(
+                lambda t: run_moe(t, min(S, cfg.moe_chunk)),
+                flat.reshape(nc, cfg.moe_chunk, d))
             out = out.reshape(T, d)
             aux = jnp.mean(aux)
+            st = {"local_tokens": jnp.sum(st["local_tokens"]),
+                  "max_expert_load": jnp.max(st["max_expert_load"]),
+                  "dropped": jnp.sum(st["dropped"])}
         else:
-            out, aux = run_moe(flat)
-        return out.reshape(B, S, d), aux
+            out, aux, st = run_moe(flat, S)
+        return out.reshape(B, S, d), aux, st
     hh = h @ lp["ffn"]["wi"].astype(h.dtype)
     hh = sh.constrain(hh, ("act_batch", "act_seq", "act_mlp"), rt)
     if cfg.use_glu:
@@ -240,7 +348,11 @@ def _ffn(lp, h, cfg: TransformerConfig, moe_on: bool):
     else:
         hh = layers.activate(hh, cfg.activation)
     out = hh @ lp["ffn"]["wo"].astype(h.dtype)
-    return out, jnp.float32(0.0)
+    return out, jnp.float32(0.0), _NO_STATS
+
+
+#: the expert-layer counters a dense layer contributes (see moe.moe_block)
+_NO_STATS = {"local_tokens": 0.0, "max_expert_load": 0.0, "dropped": 0.0}
 
 
 def _norm(lp, name, x, cfg: TransformerConfig):
@@ -249,17 +361,23 @@ def _norm(lp, name, x, cfg: TransformerConfig):
 
 
 def _layer_train(x, lp, cfg: TransformerConfig, positions, kvq_l, moe_on):
-    """Full-sequence layer forward. Returns (x, (aux, kv_dist))."""
+    """Full-sequence layer forward. Returns (x, (aux, kv_dist, stats))."""
     rt = cfg.rule_table
-    h = _norm(lp, "ln1", x, cfg)
-    q, k, v = _qkv(lp, h, cfg, positions)
-    q = sh.constrain(q, ("act_batch", "act_seq", "act_heads", None), rt)
-    att = layers.blockwise_attention(q, k, v, q_chunk=cfg.q_chunk)
     B, S = x.shape[:2]
-    att = att.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    x = x + att @ lp["attn"]["wo"].astype(x.dtype)
+    h = _norm(lp, "ln1", x, cfg)
+    mla = cfg.mla is not None
+    with jax.named_scope("mla") if mla else contextlib.nullcontext():
+        if mla:
+            q, k, v, c = _mla_qkv(lp, h, cfg, positions)
+        else:
+            q, k, v = _qkv(lp, h, cfg, positions)
+        q = sh.constrain(q, ("act_batch", "act_seq", "act_heads", None), rt)
+        att = layers.blockwise_attention(
+            q, k, v, q_chunk=cfg.q_chunk,
+            scale=cfg.mla.softmax_scale if mla else None)
+        x = x + att.reshape(B, S, -1) @ lp["attn"]["wo"].astype(x.dtype)
     h2 = _norm(lp, "ln2", x, cfg)
-    y, aux = _ffn(lp, h2, cfg, moe_on)
+    y, aux, stats = _ffn(lp, h2, cfg, moe_on)
     x = x + y
     # act_boundary_seq: None normally; "model" under *_bigtrain rules so the
     # residual saved for backward is stored seq-sharded (ZeRO-activations).
@@ -267,13 +385,19 @@ def _layer_train(x, lp, cfg: TransformerConfig, positions, kvq_l, moe_on):
 
     kv_dist = jnp.float32(0.0)
     if cfg.train_kv_quant and kvq_l is not None:
-        # Distortion term on a subsample of this layer's K/V vectors — the
-        # paper's Eq. (1) second term for the KV index.
-        kvp = _kvq_params(kvq_l)
-        ks = k[:, : min(64, S)].reshape(-1, cfg.head_dim)
-        vs = v[:, : min(64, S)].reshape(-1, cfg.head_dim)
-        kv_dist = kv_quant.kv_distortion(kvp, ks, vs)
-    return x, (aux, kv_dist)
+        if mla:
+            # the paper's Eq. (1) second term on every token's latent
+            with jax.named_scope("mla.kvq"):
+                kv_dist = kv_quant.latent_distortion(
+                    kvq_l["rot_c"], kvq_l["cb_c"], c)
+        else:
+            # Distortion term on a subsample of this layer's K/V vectors —
+            # the paper's Eq. (1) second term for the KV index.
+            kvp = _kvq_params(kvq_l)
+            ks = k[:, : min(64, S)].reshape(-1, cfg.head_dim)
+            vs = v[:, : min(64, S)].reshape(-1, cfg.head_dim)
+            kv_dist = kv_quant.kv_distortion(kvp, ks, vs)
+    return x, (aux, kv_dist, stats)
 
 
 def _constrain_grouped(grouped, params, cfg: TransformerConfig):
@@ -320,29 +444,60 @@ def _maybe_remat(fn, cfg: TransformerConfig):
 # Training forward
 # ---------------------------------------------------------------------------
 
+def _add(acc, aux, dist, stats):
+    """Fold one layer's (aux, distortion, counters) into the running totals:
+    sums, and the largest expert load."""
+    return {"aux": acc["aux"] + aux, "dist": acc["dist"] + dist,
+            "local_tokens": acc["local_tokens"] + stats["local_tokens"],
+            "max_expert_load": jnp.maximum(acc["max_expert_load"],
+                                           stats["max_expert_load"]),
+            "dropped": acc["dropped"] + stats["dropped"]}
+
+
 def forward_train(params, tokens: jax.Array, labels: jax.Array,
                   cfg: TransformerConfig) -> jax.Array:
     """tokens/labels (B, S) int32 → scalar loss."""
+    return forward_train_stats(params, tokens, labels, cfg)[0]
+
+
+def forward_train_stats(params, tokens: jax.Array, labels: jax.Array,
+                        cfg: TransformerConfig):
+    """tokens/labels (B, S) int32 → (scalar loss, stats). stats (MoE
+    configs only) holds the expert layers' counters: ``moe.local_tokens``
+    (assignments to held experts, mean over expert layers),
+    ``moe.max_expert_load`` (largest group) and ``moe.dropped`` (0)."""
     rt = cfg.rule_table
     B, S = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     x = sh.constrain(x, ("act_batch", "act_seq", "act_embed"), rt)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     fn = _maybe_remat(_layer_train, cfg)
+    f32 = jnp.float32(0.0)
+    acc = {"aux": f32, "dist": f32, "local_tokens": f32,
+           "max_expert_load": f32, "dropped": f32}
+
+    kvq_all = params.get("kvq")
+    for i in range(cfg.first_dense_layers):
+        lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
+        kvq_l = (None if kvq_all is None
+                 else jax.tree.map(lambda a: a[i], kvq_all))
+        x, (aux, dist, st) = fn(x, lp, cfg, positions, kvq_l, False)
+        acc = _add(acc, aux, dist, st)
 
     def body(carry, scanned):
-        x, aux_t, dist_t = carry
+        x, acc = carry
         lp, kvq_l = scanned
         if cfg.interleaved:
-            x, (a1, d1) = fn(x, lp["a"], cfg, positions, _kvq_sub(kvq_l, 0), False)
-            x, (a2, d2) = fn(x, lp["b"], cfg, positions, _kvq_sub(kvq_l, 1), True)
-            aux, dist = a1 + a2, d1 + d2
+            x, (a1, d1, s1) = fn(x, lp["a"], cfg, positions, _kvq_sub(kvq_l, 0), False)
+            x, (a2, d2, s2) = fn(x, lp["b"], cfg, positions, _kvq_sub(kvq_l, 1), True)
+            acc = _add(_add(acc, a1, d1, s1), a2, d2, s2)
         else:
-            x, (aux, dist) = fn(x, lp, cfg, positions, kvq_l, cfg.moe is not None)
-        return (x, aux_t + aux, dist_t + dist), None
+            x, (aux, dist, st) = fn(x, lp, cfg, positions, kvq_l, cfg.moe is not None)
+            acc = _add(acc, aux, dist, st)
+        return (x, acc), None
 
     scanned = (params["layers"], _kvq_scan_tree(params, cfg))
-    carry0 = (x, jnp.float32(0.0), jnp.float32(0.0))
+    carry0 = (x, acc)
     G = cfg.scan_groups
     if G > 1:
         assert cfg.scan_len % G == 0
@@ -360,18 +515,24 @@ def forward_train(params, tokens: jax.Array, labels: jax.Array,
             carry, _ = jax.lax.scan(body, carry, group_xs)
             return carry, None
 
-        (x, aux, dist), _ = jax.lax.scan(run_group, carry0, grouped)
+        (x, acc), _ = jax.lax.scan(run_group, carry0, grouped)
     else:
-        (x, aux, dist), _ = jax.lax.scan(body, carry0, scanned)
+        (x, acc), _ = jax.lax.scan(body, carry0, scanned)
     x = _final_norm(params, x, cfg)
     loss = layers.softmax_xent_chunked(
         x.reshape(B * S, cfg.d_model), params["head"], labels.reshape(-1),
         chunk=cfg.xent_chunk,
     )
-    total = loss + 0.01 * aux / cfg.num_layers
+    total = loss
+    stats = {}
+    if cfg.moe is not None:
+        total = total + cfg.moe.aux_alpha * acc["aux"]
+        stats = {"moe.local_tokens": acc["local_tokens"] / cfg.scan_len,
+                 "moe.max_expert_load": acc["max_expert_load"],
+                 "moe.dropped": acc["dropped"]}
     if cfg.train_kv_quant and "kvq" in params:
-        total = total + 0.1 * dist / cfg.num_layers
-    return total
+        total = total + 0.1 * acc["dist"] / cfg.num_layers
+    return total, stats
 
 
 def _final_norm(params, x, cfg: TransformerConfig):
@@ -440,8 +601,15 @@ def _decode_sublayer(x, lp, cfg: TransformerConfig, pos, kvq_l, moe_on,
         att = layers.decode_attention(q, kc, vc, pos + 1)
     x = x + att.reshape(B, Hq * hd) @ lp["attn"]["wo"].astype(x.dtype)
     h2 = _norm(lp, "ln2", x[:, None], cfg)
-    y, _aux = _ffn(lp, h2, cfg, moe_on)
+    y, _aux, _ = _ffn(lp, h2, cfg, moe_on)
     return x + y[:, 0], kc, vc
+
+
+def _serve_supported(cfg: TransformerConfig) -> None:
+    if cfg.mla is not None or cfg.first_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: the serve paths have no latent cache and no "
+            "leading dense layers; only the training forward runs MLA")
 
 
 def serve_decode(params, token: jax.Array, cache, cfg: TransformerConfig):
@@ -451,6 +619,7 @@ def serve_decode(params, token: jax.Array, cache, cfg: TransformerConfig):
     dynamic_update_slice — emitting per-layer caches as scan ys doubles the
     cache footprint (input stack + output stack both live; measured 62 vs
     ~10 GiB/device on nemotron decode_32k)."""
+    _serve_supported(cfg)
     rt = cfg.rule_table
     x = jnp.take(params["embed"], token, axis=0).astype(cfg.dtype)  # (B, d)
     pos = cache.length  # (B,)
@@ -504,7 +673,7 @@ def _prefill_sublayer(x, lp, cfg, positions, kvq_l, moe_on, quantized, rt):
     att = att.reshape(B, S, cfg.num_heads * cfg.head_dim)
     x = x + att @ lp["attn"]["wo"].astype(x.dtype)
     h2 = _norm(lp, "ln2", x, cfg)
-    y, _aux = _ffn(lp, h2, cfg, moe_on)
+    y, _aux, _ = _ffn(lp, h2, cfg, moe_on)
     x = x + y
     kt = k.transpose(0, 2, 1, 3)  # (B, Hkv, S, hd)
     vt = v.transpose(0, 2, 1, 3)
@@ -518,6 +687,7 @@ def _prefill_sublayer(x, lp, cfg, positions, kvq_l, moe_on, quantized, rt):
 def serve_prefill(params, tokens: jax.Array, cfg: TransformerConfig,
                   max_len: int | None = None):
     """tokens (B, S) → (last-token logits, populated cache of size max_len)."""
+    _serve_supported(cfg)
     rt = cfg.rule_table
     B, S = tokens.shape
     max_len = max_len or S
@@ -563,14 +733,24 @@ def model_flops_per_token(cfg: TransformerConfig) -> float:
     """6·N_active — the §Roofline MODEL_FLOPS numerator per token."""
     d, f, L = cfg.d_model, cfg.d_ff, cfg.num_layers
     Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    attn = d * (Hq + 2 * Hkv) * hd + Hq * hd * d
+    if cfg.mla is not None:
+        m = cfg.mla
+        attn = (d * Hq * m.q_head_dim + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                + m.kv_lora_rank * Hq * (m.qk_nope_head_dim + m.v_head_dim)
+                + Hq * m.v_head_dim * d)
+    else:
+        attn = d * (Hq + 2 * Hkv) * hd + Hq * hd * d
     n_mats = 3 if cfg.use_glu else 2
     dense_ffn = n_mats * d * f
-    moe_ffn = (cfg.moe.top_k * n_mats * d * f) if cfg.moe is not None else 0.0
+    moe_ffn = 0.0
+    if cfg.moe is not None:
+        moe_ffn = (cfg.moe.top_k * n_mats * d * (cfg.moe.d_ff or f)
+                   + 3 * d * cfg.moe.shared_ff)
     if cfg.interleaved:
         ffn_total = (L // 2) * dense_ffn + (L // 2) * moe_ffn
     elif cfg.moe is not None:
-        ffn_total = L * moe_ffn
+        ffn_total = (cfg.first_dense_layers * dense_ffn
+                     + cfg.scan_len * moe_ffn)
     else:
         ffn_total = L * dense_ffn
     head = cfg.vocab_size * d

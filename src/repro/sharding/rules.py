@@ -45,9 +45,6 @@ LM_BASE_RULES: dict[str, Any] = {
     #                              shard these over "model" (ZeRO-activations)
     "act_mlp": "model",
     "act_vocab": "model",
-    "act_experts": "model",      # MoE dispatch buffer expert dim (EP)
-    "act_capacity": "data",      # MoE dispatch buffer capacity dim
-    "act_expert_mlp": "model",   # expert hidden dim (takes over when E < 16)
     "act_tokens": "data",        # flattened token dim in MoE dispatch
     # --- weights ---
     # ZeRO/FSDP axis; "pod" is filtered out automatically on the single-pod

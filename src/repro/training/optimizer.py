@@ -2,13 +2,14 @@
 
 Ordinary parameters get AdamW (configurable moment dtype — bf16 moments for
 the ≥100B archs, see DESIGN.md §6). Any leaf whose name is in
-``MANIFOLD_LEAVES`` ({'R', 'rot_k', 'rot_v'}) is an SO(n) rotation and is
+``MANIFOLD_LEAVES`` ({'R', 'rot_k', 'rot_v', 'rot_c'}) is an SO(n) rotation and is
 routed through the ``repro.rotations`` learner configured by
 ``OptimizerConfig.rotation`` instead — GCD (the paper's Algorithm 2,
 projection-free and exactly orthogonal at every step), Cayley-SGD,
 SVD/Procrustes, or the frozen-R control, all swappable by registry spec.
 Stacked rotations (leading layer axis, e.g. per-layer KV rotations
-(L, hd, hd)) are vmapped over the learner's update.
+(L, hd, hd) or latent rotations (L, r, r)) are vmapped over the learner's
+update.
 
 This is the paper's headline integration claim: GCD "can be easily
 integrated with standard neural network training algorithms" — and with the
@@ -24,7 +25,7 @@ import jax.numpy as jnp
 
 from repro import rotations as rot_lib
 
-MANIFOLD_LEAVES = ("R", "rot_k", "rot_v")
+MANIFOLD_LEAVES = ("R", "rot_k", "rot_v", "rot_c")
 
 
 class OptimizerConfig(NamedTuple):

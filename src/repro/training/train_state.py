@@ -64,8 +64,13 @@ def make_train_step(
     opt_cfg: opt_lib.OptimizerConfig,
     grad_shardings=None,
     emit_deltas: bool = False,
+    has_aux: bool = False,
 ) -> Callable:
     """loss_fn(params, *batch_arrays) -> scalar. Returns a pure step fn.
+
+    ``has_aux=True``: loss_fn returns (scalar, {name: scalar}) and the named
+    scalars join the step's metrics (e.g. an expert layer's counters);
+    microbatch accumulation then takes no aux.
 
     ``emit_deltas=True`` adds ``metrics["rotation_deltas"]`` — the
     ``{path_key: RotationDelta}`` dict each manifold update applied, ready
@@ -90,10 +95,17 @@ def make_train_step(
             lambda g, s: jax.lax.with_sharding_constraint(g, s),
             grads, grad_shardings)
 
+    if has_aux and A > 1:
+        raise ValueError("has_aux needs accum_steps == 1")
+
     def _grads(params, *batch):
+        if has_aux:
+            (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, *batch)
+            return loss, _pin(g), aux
         if A == 1:
             loss, g = jax.value_and_grad(loss_fn)(params, *batch)
-            return loss, _pin(g)
+            return loss, _pin(g), {}
         micro = jax.tree.map(
             lambda x: x.reshape(A, x.shape[0] // A, *x.shape[1:]), batch)
         gz = _pin(jax.tree.map(
@@ -115,10 +127,10 @@ def make_train_step(
             return (lsum + l, gsum), None
 
         (loss, grads), _ = jax.lax.scan(mb, (jnp.float32(0.0), gz), micro)
-        return loss, grads
+        return loss, grads, {}
 
     def train_step(state: TrainState, *batch) -> tuple[TrainState, dict]:
-        loss, grads = _grads(state.params, *batch)
+        loss, grads, aux = _grads(state.params, *batch)
         key, sub = jax.random.split(state.rng)
         if emit_deltas:
             params, opt_state, deltas = opt_lib.update_with_deltas(
@@ -132,6 +144,7 @@ def make_train_step(
             "loss": loss.astype(jnp.float32),
             "grad_norm": opt_lib.global_norm(grads),
             "lr": opt_lib.schedule_lr(opt_cfg, state.step),
+            **aux,
         }
         if emit_deltas:
             metrics["rotation_deltas"] = deltas

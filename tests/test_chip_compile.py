@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiles for a TPU v5e at the paper's widths.
+"""Every Pallas kernel compiles for a TPU v5e at the paper's widths, and
+the expert layer's grouped matmul at DeepSeek-V2-Lite's.
 
 Interpret mode cannot see what the TPU compiler refuses: blocks that break
 the (8, 128) tiling rule, tiles that overflow the scoped VMEM limit, shape
@@ -112,6 +113,13 @@ KERNELS = {
     "adc_batch": (
         lambda lut, c: adc_batch.adc_batch(lut, c, interpret=False),
         [((64, 4, 16, 256), F32), ((64, 4096, 16), U8)]),
+    # the dropless expert layer's grouped matmul (XLA's own TPU kernel) at
+    # DeepSeek-V2-Lite's widths: 2 × 8,192 tokens × top-6 routed rows, the
+    # 8 experts one chip holds, 2,048 → 1,408
+    "moe_grouped_matmul": (
+        jax.lax.ragged_dot,
+        [((16384 * 6, 2048), jnp.bfloat16), ((8, 2048, 1408), jnp.bfloat16),
+         ((8,), I32)]),
 }
 
 

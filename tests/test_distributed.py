@@ -414,3 +414,53 @@ def test_production_mesh_shapes():
         }))
     """))
     assert res["single"] and res["multi"] and res["axes"]
+
+
+@pytest.mark.parametrize("experts", [8, 2])
+def test_expert_parallel_moe_matches_one_device(experts):
+    """The dropless expert layer under a (data 2, model 4) mesh — each
+    device its share of the experts (8 experts: 2 a device) or, when the
+    experts do not divide the model axis (2), its slice of every expert's
+    width — gives the one-device layer's output, balance loss, counters and
+    gradients."""
+    res = _run(HEADER + textwrap.dedent(f"""
+        from repro.models import moe
+        from repro.sharding import rules as sh
+        E, k, T, d, f = {experts}, 2, 64, 16, 32
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        x = jax.random.normal(ks[0], (T, d))
+        args = (jax.random.normal(ks[1], (d, E)),
+                jax.random.normal(ks[2], (E, d, f)) / 4,
+                jax.random.normal(ks[3], (E, d, f)) / 4,
+                jax.random.normal(ks[4], (E, f, d)) / 6)
+        cfg = moe.MoEConfig(num_experts=E, top_k=k, norm_topk_prob=False,
+                            seq_aux=True)
+        rules = sh.RULE_REGISTRY["lm_base"]
+
+        def loss(x, *w):
+            out, aux, st = moe.moe_block(x, *w, cfg, activation="silu",
+                                         rule_table=rules, seq_len=16)
+            return jnp.sum(out * jnp.cos(out)) + aux, (out, aux, st)
+
+        f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                       has_aux=True))
+        with jax.default_matmul_precision("highest"):
+            (_, one), g1 = f(x, *args)
+            mesh = make_mesh_compat((2, 4), ("data", "model"))
+            with mesh:
+                (_, many), g8 = f(x, *args)
+                hlo = f.lower(x, *args).as_text()
+        rel = lambda a, b: float(np.linalg.norm(np.asarray(a) - np.asarray(b))
+                                 / np.linalg.norm(np.asarray(b)))
+        print(json.dumps({{
+            "out": rel(many[0], one[0]), "aux": rel(many[1], one[1]),
+            "stats": [float(many[2][n]) == float(one[2][n])
+                      for n in ("local_tokens", "max_expert_load", "dropped")],
+            "grads": max(rel(a, b) for a, b in zip(g8, g1)),
+            "psum": "all_reduce" in hlo or "all-reduce" in hlo,
+        }}))
+    """))
+    assert res["out"] < 1e-5 and res["aux"] < 1e-6, res
+    assert all(res["stats"]), res
+    assert res["grads"] < 1e-5, res
+    assert res["psum"], res
