@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.kernels import adc_batch as _adcb
 from repro.kernels import adc_lookup as _adc
 from repro.kernels import embedding_bag as _bag
+from repro.kernels import flash_attention as _fa
 from repro.kernels import gcd_score as _score
 from repro.kernels import givens_rotate as _rot
 from repro.kernels import ivf_adc as _ivf
@@ -28,7 +29,7 @@ from repro.kernels.adc_common import (LUT_DTYPES, dequantize_luts,
 __all__ = [
     "apply_pair_rotations", "gcd_score", "pq_assign", "adc_lookup",
     "adc_batch", "ivf_adc", "fused_lut", "embedding_bag", "topk_merge",
-    "quantize_luts", "dequantize_luts", "LUT_DTYPES",
+    "flash_attention", "quantize_luts", "dequantize_luts", "LUT_DTYPES",
 ]
 
 
@@ -164,6 +165,27 @@ def embedding_bag(table, indices, bag_ids, num_bags: int, weights=None, *,
     if use_kernel:
         return _bag.embedding_bag(table, indices, bag_ids, num_bags, weights)
     return ref.embedding_bag_ref(table, indices, bag_ids, num_bags, weights)
+
+
+@functools.partial(jax.jit, static_argnames=("q_chunk", "causal", "scale",
+                                             "use_kernel"))
+def flash_attention(q, k, v, *, scale: float | None = None, q_chunk: int = 256,
+                    causal: bool = True, use_kernel: bool = True):
+    """Attention q (B, S, Hq, dk), k (B, S, Hkv, dk), v (B, S, Hkv, dv) ->
+    (B, S, Hq, dv), softmax(scale · q kᵀ) with ``scale`` defaulting to
+    dk^-0.5 (GQA: query head h reads kv head h // (Hq/Hkv)). The kernel is
+    causal and runs head-major with blocks from the sequence length, which
+    must tile into 128-row blocks (else ValueError); ``q_chunk`` and
+    ``causal=False`` belong to the chunked oracle."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    if not use_kernel:
+        return ref.flash_attention_ref(q, k, v, q_chunk=q_chunk, causal=causal,
+                                       scale=scale)
+    if not causal:
+        raise ValueError("the flash-attention kernel is causal only")
+    heads_major = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    return heads_major(_fa.flash_attention(
+        heads_major(q), heads_major(k), heads_major(v), scale=scale))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -36,7 +36,7 @@ from repro.data import pipeline as pipe_lib
 from repro.data import synthetic
 from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
-from repro.models import gnn, recsys
+from repro.models import gnn, layers, recsys
 from repro.models import transformer as tfm
 from repro.training import checkpoint as ckpt
 from repro.training import optimizer as opt_lib
@@ -202,6 +202,11 @@ def train(arch_id: str, steps: int, batch: int, ckpt_dir: str | None,
             pipe.restore(pipe_state)
             start_step = latest
             print(f"[train] resumed from step {latest}")
+
+    if obs.enabled() and arch.family == "lm":
+        # the attention kernel's causal skip, from its static grid
+        for name, value in layers.attention_tiles(cfg.train_seq_len).items():
+            reg.gauge(name).set(value)
 
     times: list[float] = []
     metrics_hist = []

@@ -1,9 +1,8 @@
 """Shared transformer layer primitives: norms, RoPE, activations, attention.
 
-Attention is implemented blockwise (scan over query chunks, f32 softmax per
-chunk) so the (B, H, S, S) score tensor is never materialized — the pure-JAX
-equivalent of flash attention, sized so the per-chunk score tile stays within
-a few GB/device at the production shapes (see DESIGN.md §3).
+Training and prefill attention never materialize the (B, H, S, S) score
+tensor: on a TPU a fused Pallas flash-attention kernel holds each score tile
+in VMEM; elsewhere a jnp oracle scans query chunks with an f32 softmax.
 """
 from __future__ import annotations
 
@@ -14,6 +13,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import compat
+from repro.kernels import flash_attention, ops
+from repro.kernels.common import on_tpu
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows finite
 
@@ -142,7 +145,26 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
 # Blockwise causal attention (training / prefill)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("q_chunk", "causal", "scale"))
+def flash_kernel_applies(seq_len: int, causal: bool = True) -> bool:
+    """Whether ``blockwise_attention`` runs the fused Pallas kernel: causal,
+    on a TPU, a sequence that tiles into the kernel's blocks, and no mesh of
+    several devices (GSPMD cannot split a custom call; the oracle shards)."""
+    mesh = compat.current_mesh()
+    return (causal and on_tpu() and flash_attention.blocks(seq_len) is not None
+            and (mesh is None or mesh.size == 1))
+
+
+def attention_tiles(seq_len: int) -> dict[str, int]:
+    """The fused kernel's kv tiles per (batch, head) of one forward call —
+    computed, and in its grid — from the static grid at this sequence
+    length; empty where ``blockwise_attention`` takes the oracle."""
+    if not flash_kernel_applies(seq_len):
+        return {}
+    done, total = flash_attention.kv_tiles(seq_len,
+                                           flash_attention.blocks(seq_len))
+    return {"attn.kv_tiles_computed": done, "attn.kv_tiles_total": total}
+
+
 def blockwise_attention(
     q: jax.Array,  # (B, S, Hq, hd)
     k: jax.Array,  # (B, S, Hkv, hd)
@@ -152,50 +174,16 @@ def blockwise_attention(
     causal: bool = True,
     scale: float | None = None,
 ) -> jax.Array:
-    """Causal GQA attention, scanned over query chunks with f32 softmax.
-
-    Peak temp is (B, Hkv, rep, q_chunk, S) f32 per chunk instead of the full
-    (B, H, S, S) score tensor. ``scale`` defaults to hd^-0.5; values may
+    """Causal GQA attention; ``scale`` defaults to hd^-0.5 and values may
     have their own width hv (MLA). Returns (B, S, Hq, hv).
+
+    The fused flash-attention kernel (``kernels/flash_attention.py``) runs
+    it where ``flash_kernel_applies``; elsewhere the jnp oracle scans query
+    chunks of ``q_chunk`` (``kernels/ref.py`` ``flash_attention_ref``).
     """
-    B, S, Hq, hd = q.shape
-    Hkv = k.shape[2]
-    hv = v.shape[-1]
-    rep = Hq // Hkv
-    scale = hd**-0.5 if scale is None else scale
-    q_chunk = min(q_chunk, S)
-    assert S % q_chunk == 0, f"seq {S} must be divisible by q_chunk {q_chunk}"
-    nq = S // q_chunk
-
-    qg = q.reshape(B, S, Hkv, rep, hd).transpose(0, 2, 3, 1, 4)  # (B,Hkv,rep,S,hd)
-    kg = k.transpose(0, 2, 1, 3)  # (B, Hkv, S, hd)
-    vg = v.transpose(0, 2, 1, 3)
-    qg = qg.reshape(B, Hkv, rep, nq, q_chunk, hd).transpose(3, 0, 1, 2, 4, 5)
-    kv_pos = jnp.arange(S)
-
-    # jax.checkpoint per chunk: without it the q-chunk scan stacks each
-    # chunk's softmax residuals, resurrecting the full (B,H,S,S) tensor in
-    # the backward pass (measured: 213 GiB/dev at the 4k-train shape). With
-    # it, the bwd recomputes scores chunk-by-chunk — flash-attention
-    # semantics in pure JAX.
-    @jax.checkpoint
-    def one_chunk(ci, qc):
-        # qc: (B, Hkv, rep, q_chunk, hd)
-        scores = jnp.einsum(
-            "bhrqd,bhsd->bhrqs", qc.astype(jnp.float32), kg.astype(jnp.float32)
-        ) * scale
-        if causal:
-            q_pos = ci * q_chunk + jnp.arange(q_chunk)
-            mask = q_pos[:, None] >= kv_pos[None, :]  # (q_chunk, S)
-            scores = jnp.where(mask[None, None, None], scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhrqs,bhsd->bhrqd", w, vg.astype(jnp.float32))
-        return out.astype(q.dtype)
-
-    outs = jax.lax.map(lambda args: one_chunk(*args), (jnp.arange(nq), qg))
-    # (nq, B, Hkv, rep, q_chunk, hd) -> (B, S, Hq, hd)
-    outs = outs.transpose(1, 2, 3, 0, 4, 5).reshape(B, Hkv, rep, S, hv)
-    return outs.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, hv)
+    return ops.flash_attention(
+        q, k, v, q_chunk=q_chunk, causal=causal, scale=scale,
+        use_kernel=flash_kernel_applies(q.shape[1], causal))
 
 
 def decode_attention(

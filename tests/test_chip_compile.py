@@ -1,5 +1,6 @@
 """Every Pallas kernel compiles for a TPU v5e at the paper's widths, and
-the expert layer's grouped matmul at DeepSeek-V2-Lite's.
+the attention kernel and the expert layer's grouped matmul at
+DeepSeek-V2-Lite's.
 
 Interpret mode cannot see what the TPU compiler refuses: blocks that break
 the (8, 128) tiling rule, tiles that overflow the scoped VMEM limit, shape
@@ -20,8 +21,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import (adc_batch, adc_lookup, embedding_bag, gcd_score,
-                           givens_rotate, ivf_adc, lut_build, pq_assign)
+from repro.kernels import (adc_batch, adc_lookup, embedding_bag,
+                           flash_attention, gcd_score, givens_rotate, ivf_adc,
+                           lut_build, pq_assign)
 
 N_DIM = 512            # embedding width
 DP, K = 64, 256        # code columns × codewords
@@ -31,6 +33,21 @@ B = 64                 # query batch
 # 16 blocks per list, a schedule longer than SMEM holds in one call
 STEPS = 131072
 F32, U8, I8, I32 = jnp.float32, jnp.uint8, jnp.int8, jnp.int32
+BF16 = jnp.bfloat16
+# DeepSeek-V2-Lite's latent attention at the training cell's shape: batch 2
+# × 8,192 tokens, 16 heads, q/k 192 (128 nope + 64 rope), v 128
+MLA_Q = ((2, 16, 8192, 192), BF16)
+MLA_V = ((2, 16, 8192, 128), BF16)
+
+
+def _mla_attention(q, k, v):
+    return flash_attention.flash_attention(q, k, v, scale=192 ** -0.5,
+                                           interpret=False)
+
+
+def _mla_attention_grads(q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(_mla_attention(q, k, v).astype(
+        F32)), argnums=(0, 1, 2))(q, k, v)
 
 
 @pytest.fixture(scope="module")
@@ -113,6 +130,9 @@ KERNELS = {
     "adc_batch": (
         lambda lut, c: adc_batch.adc_batch(lut, c, interpret=False),
         [((64, 4, 16, 256), F32), ((64, 4096, 16), U8)]),
+    # the training step's attention: forward, and the dq and dk/dv kernels
+    "flash_attention_fwd": (_mla_attention, [MLA_Q, MLA_Q, MLA_V]),
+    "flash_attention_bwd": (_mla_attention_grads, [MLA_Q, MLA_Q, MLA_V]),
     # the dropless expert layer's grouped matmul (XLA's own TPU kernel) at
     # DeepSeek-V2-Lite's widths: 2 × 8,192 tokens × top-6 routed rows, the
     # 8 experts one chip holds, 2,048 → 1,408

@@ -1,5 +1,8 @@
 """Pallas kernels: shape/dtype sweeps + hypothesis, allclose vs ref.py
 oracles. interpret=True on CPU per the deliverable contract."""
+import functools
+from types import SimpleNamespace
+
 import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
@@ -8,7 +11,8 @@ import pytest
 from hypothesis import given, settings
 
 from repro.core import givens
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention, ops, ref
+from repro.models import layers
 
 
 @pytest.mark.parametrize("m,n", [(8, 8), (64, 32), (100, 64), (257, 128)])
@@ -372,3 +376,108 @@ def test_ivf_adc_mask_property(holes, nblocks, bs, b, quantized):
     plain = np.asarray(ops.ivf_adc(lut, codes, block_idx, block_query,
                                    scales, block_size=bs))
     np.testing.assert_array_equal(got[~dead], plain[~dead])
+
+
+# ---------------------------------------------------------------------------
+# Causal flash attention (training / prefill) against the chunked oracle
+# ---------------------------------------------------------------------------
+
+def _heads_major(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+# (B, S, Hq, Hkv, dk, dv, dtype, tolerance); the kernel's own blocks:
+# 128 rows at S 384 and 640, one 256-row block at S 256
+FLASH_CASES = {
+    # DeepSeek-V2 MLA: q/k 128 nope + 64 rope, v 128; 3 of 9 tiles skipped
+    "mla_widths": (1, 384, 2, 2, 192, 128, jnp.float32, 1e-5),
+    # GQA: two query heads read each kv head (forward map and dk/dv sum)
+    "gqa_rep2": (2, 384, 4, 2, 32, 32, jnp.float32, 1e-5),
+    # five blocks: 10 of 25 tiles skipped, most of each late row computed
+    "many_tiles": (1, 640, 2, 1, 24, 16, jnp.float32, 1e-5),
+    # one block: nothing skipped, every entry above the diagonal masked
+    "single_tile": (1, 256, 2, 2, 24, 16, jnp.float32, 1e-5),
+    # the training dtype: bf16 operands, f32 accumulation on both sides
+    "bf16": (2, 384, 4, 2, 192, 128, jnp.bfloat16, 2e-2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_attention_matches_oracle(case):
+    """The kernel (interpreted) gives the oracle's output and its q, k and
+    v gradients, with the causal skip, GQA and MLA's unequal widths."""
+    B, S, Hq, Hkv, dk, dv, dtype, tol = FLASH_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(S + Hq + dk), 4)
+    q = jax.random.normal(keys[0], (B, S, Hq, dk)).astype(dtype)
+    k = jax.random.normal(keys[1], (B, S, Hkv, dk)).astype(dtype)
+    v = jax.random.normal(keys[2], (B, S, Hkv, dv)).astype(dtype)
+    w = jax.random.normal(keys[3], (B, S, Hq, dv))
+    scale = 0.3
+
+    def kernel(q, k, v):
+        return _heads_major(flash_attention.flash_attention(
+            _heads_major(q), _heads_major(k), _heads_major(v), scale=scale))
+
+    def oracle(q, k, v):
+        return ref.flash_attention_ref(q, k, v, q_chunk=128, scale=scale)
+
+    def grads(f):
+        return jax.grad(lambda q, k, v: jnp.sum(f(q, k, v).astype(
+            jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+
+    assert _rel(kernel(q, k, v), oracle(q, k, v)) < tol
+    for got, want in zip(grads(kernel), grads(oracle)):
+        assert got.dtype == dtype
+        assert _rel(got, want) < tol
+
+
+def test_flash_attention_kv_tiles():
+    """Blocks come from the sequence length, and the tile counts from the
+    grid's live predicate: the cell's shape computes 36 of 64 tiles per
+    (batch, head) (136 of 256 with 512-blocks); a length that does not
+    tile is refused."""
+    assert flash_attention.blocks(8192) == 1024
+    assert flash_attention.blocks(1536) == 512
+    assert flash_attention.blocks(384) == 128
+    assert flash_attention.blocks(200) is None
+    assert flash_attention.kv_tiles(8192, 1024) == (36, 64)
+    assert flash_attention.kv_tiles(8192, 512) == (136, 256)
+    for S, blk in ((384, 128), (640, 128), (256, 256)):
+        n = S // blk
+        live = sum(j <= i for i in range(n) for j in range(n))
+        assert flash_attention.kv_tiles(S, blk) == (live, n * n)
+    x = jnp.zeros((1, 1, 200, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="does not tile"):
+        flash_attention.flash_attention(x, x, x, scale=1.0)
+
+
+@pytest.mark.parametrize("seq_len,causal,tpu,mesh_size,kernel", [
+    (384, True, True, None, True),      # three 128-blocks: the kernel
+    (200, True, True, None, False),     # does not tile: the oracle
+    (384, False, True, None, False),    # not causal: the oracle
+    (384, True, True, 1, True),         # a one-device mesh shards nothing
+    (384, True, True, 2, False),        # GSPMD cannot split the custom call
+    (384, True, False, None, False),    # no TPU: the oracle
+])
+def test_blockwise_attention_dispatch(monkeypatch, seq_len, causal, tpu,
+                                      mesh_size, kernel):
+    """``blockwise_attention`` takes the kernel on a TPU where the shapes
+    tile and no mesh shards the call, and the oracle otherwise; the
+    trainer's tile gauges follow the same choice."""
+    monkeypatch.setattr(layers, "on_tpu", lambda: tpu)
+    mesh = None if mesh_size is None else SimpleNamespace(size=mesh_size)
+    monkeypatch.setattr(layers.compat, "current_mesh", lambda: mesh)
+    assert layers.flash_kernel_applies(seq_len, causal) == kernel
+    q = jax.ShapeDtypeStruct((1, seq_len, 2, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, seq_len, 2, 128), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(functools.partial(
+        layers.blockwise_attention, causal=causal, q_chunk=8))(q, q, v))
+    assert ("pallas_call" in jaxpr) == kernel
+    tiles = layers.attention_tiles(seq_len) if causal else {}
+    assert tiles == ({"attn.kv_tiles_computed": 6, "attn.kv_tiles_total": 9}
+                     if kernel else {})
